@@ -21,9 +21,9 @@ Three stages, all from *measured* traces on this container:
    exact extraction) for the per-op latency and per-byte cost.
 
 The result is saved as ``calibration.json`` at the repo root (the artifact
-CI uploads; `repro.analysis.costmodel.load_calibration` finds it there) and
-``--write-default`` refreshes the committed hermetic cold-start table in
-``src/repro/analysis/calibration_default.json``.
+CI uploads; `strategy="auto"` uses it only when ``$REPRO_CALIBRATION``
+names it) and ``--write-default`` refreshes the committed hermetic
+cold-start table in ``src/repro/analysis/calibration_default.json``.
 
 The ``autotune`` bench section (schema v9) then demonstrates the acceptance
 criterion: resolve ``strategy="auto"`` under the fresh calibration, measure
@@ -66,7 +66,7 @@ def collect_samples(smoke: bool, repeats: int = 5) -> dict:
     """Primitive samples per (backend, compute dtype) across the shape sweep."""
     import contextlib
 
-    from jax.experimental import enable_x64
+    import jax
 
     from repro.api.config import SolverConfig
     from repro.api.hotloop import profile_primitives
@@ -82,7 +82,7 @@ def collect_samples(smoke: bool, repeats: int = 5) -> dict:
             cfg_kw = dict(dtype="float32", compute_dtype="bfloat16")
         else:
             cfg_kw = dict(dtype=dtype)
-        ctx = enable_x64() if dtype == "float64" else contextlib.nullcontext()
+        ctx = jax.enable_x64(True) if dtype == "float64" else contextlib.nullcontext()
         with ctx:
             for N, v in _sweep_shapes(smoke):
                 if backend == "pallas" and v % 8:
@@ -165,6 +165,7 @@ def _scale_alphas(calib, scale: float):
 
 _COLLECTIVE_WORKER = r"""
 import os
+os.environ["JAX_PLATFORMS"] = "cpu"  # host devices only, never the chip
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import sys, time, json
 sys.path.insert(0, %(src)r)

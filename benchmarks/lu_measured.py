@@ -45,11 +45,11 @@ import sys
 
 _WORKER = r"""
 import os
+os.environ["JAX_PLATFORMS"] = "cpu"  # host devices only, never the chip
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import sys, time, json
 sys.path.insert(0, %(src)r)
 import numpy as np, jax, jax.numpy as jnp
-from jax.experimental import enable_x64
 from repro.api import SolverConfig, plan, plan_cache_stats, GridConfig
 from repro.api.config import resolve_dtype
 from repro.core.lu.cost_models import chol_model, conflux_model, scalapack2d_model
@@ -280,7 +280,7 @@ def mp_run(cname, cdt):
     res = float(np.abs(A_mp @ x.astype(np.float64) - b_mp).max() / bden)
     return wall, res, iters, conv
 
-with enable_x64():  # the direct rows need genuine f64; refined rows manage
+with jax.enable_x64(True):  # the direct rows need genuine f64; refined rows manage
     for cname, cdt in mp_cases:  # their own x64 scope but are no-ops under it
         mp_run(cname, cdt)  # warm compile, untimed
         mp_walls[cname] = []

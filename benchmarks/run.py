@@ -134,10 +134,12 @@ def _section(title):
 def _audit_section(timeout: int = 900) -> dict:
     """`bench_audit_rows()` in a subprocess: the distributed combos need the
     8 host devices pinned before jax initializes (same pattern as
-    lu_measured's worker)."""
+    lu_measured's worker).  The child stays on the CPU, so it never
+    contends for an accelerator with its parent."""
     src = os.path.abspath(os.path.join(_ROOT, "src"))
     code = (
         "import os, sys, json\n"
+        "os.environ['JAX_PLATFORMS'] = 'cpu'\n"
         "os.environ['XLA_FLAGS'] = '--xla_force_host_platform_device_count=8'\n"
         f"sys.path.insert(0, {src!r})\n"
         "from repro.analysis.audit import bench_audit_rows\n"
@@ -599,6 +601,9 @@ def smoke_gate(bench: dict, baseline: dict | None,
 
 
 def main() -> None:
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache(_ROOT)
     smoke = "--smoke" in sys.argv
     if "--validate" in sys.argv:
         path = BENCH_SMOKE_JSON if smoke else BENCH_JSON

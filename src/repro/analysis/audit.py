@@ -381,6 +381,16 @@ def _ref_aval(aval):
     return getattr(aval, "inner_aval", aval)
 
 
+def _block_extent(b) -> int | None:
+    """A block dim's extent as an int (a squeezed dim is 1); None for
+    element-indexed dims.  Pallas stores a plain extent as `Blocked`."""
+    from jax.experimental import pallas as pl
+
+    if isinstance(b, pl.Squeezed):
+        return 1
+    return b.block_size if isinstance(b, pl.Blocked) else None
+
+
 def lint_pallas_fn(fn, avals, name: str,
                    vmem_budget: int = DEFAULT_VMEM_BUDGET) -> list[AuditFinding]:
     """Trace `fn(*avals)` and statically lint every pallas_call inside it."""
@@ -412,7 +422,7 @@ def lint_pallas_fn(fn, avals, name: str,
         for i, (aval, bm) in enumerate(zip(arrays, bms)):
             block = tuple(bm.block_shape)
             dims = tuple(aval.shape)
-            ints = [b for b in block if isinstance(b, int)]
+            ints = [n for n in map(_block_extent, block) if n is not None]
             if len(ints) != len(dims):
                 continue  # squeezed/mapped dims: skip rather than misalign
             bad = [(d, b) for d, b in zip(dims, ints) if b > 0 and d % b]

@@ -232,11 +232,12 @@ _active_lock = threading.Lock()
 def load_calibration(path: str | None = None) -> Calibration | None:
     """Load a calibration artifact.
 
-    Search order: explicit `path` -> $REPRO_CALIBRATION -> ./calibration.json
-    (the artifact `benchmarks.run --calibrate` writes) -> the committed
-    package default.  Returns None when nothing loadable is found (the
-    graceful-degradation contract: `auto` then falls back to the analytic
-    comm-volume ranking).
+    Search order: explicit `path` -> $REPRO_CALIBRATION -> the committed
+    package default.  A refit artifact (`benchmarks.run --calibrate` writes
+    `calibration.json`) takes effect only when named by path or by
+    $REPRO_CALIBRATION, never by sitting in the working directory.  Returns
+    None when nothing loadable is found (the graceful-degradation contract:
+    `auto` then falls back to the analytic comm-volume ranking).
     """
     candidates = []
     if path is not None:
@@ -245,7 +246,6 @@ def load_calibration(path: str | None = None) -> Calibration | None:
         env = os.environ.get(_ENV_PATH)
         if env:
             candidates.append(env)
-        candidates.append(os.path.join(os.getcwd(), "calibration.json"))
         candidates.append(_DEFAULT_TABLE)
     for cand in candidates:
         if not os.path.exists(cand):
@@ -424,12 +424,9 @@ def predict_wall(N: int, cfg=None, grid=None, v: int | None = None,
     fits = calib.fits(backend, dtype)
     if fits is None:
         return None
-    try:
-        import jax
+    import jax
 
-        platform = jax.devices()[0].platform
-    except Exception:
-        platform = calib.device_kind
+    platform = jax.devices()[0].platform
     if calib.device_kind != platform:
         return None  # a cpu-fitted table must not price a tpu run
 

@@ -33,6 +33,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.precision import full_precision
 from repro.core.windows import window_buckets
 
 
@@ -117,29 +118,33 @@ def profile_primitives(N: int, config, grid=None, repeats: int = 3) -> dict:
     own = jnp.ones((v,), dtype)
     S = jax.nn.one_hot(lr, R, dtype=dtype)  # [v, R] — the replaced one-hot
 
+    # The primitives run as in a plan: dots at full f32 precision.
+    def jit(f):
+        return jax.jit(full_precision(f))
+
     spd_kind = config.pivot == "none"
     if spd_kind:
         spd = tri @ tri.T + jnp.eye(v, dtype=dtype)
-        panel_fn, panel_args = jax.jit(lambda a: bk.panel_chol(a)), (spd,)
+        panel_fn, panel_args = jit(lambda a: bk.panel_chol(a)), (spd,)
         # step 4's solve: L10 = panel (L00^T)^-1
         trsm_fn, trsm_args = (
-            jax.jit(lambda p, l: bk.trsm_right_upper(p, l.T)), (panel, tri),
+            jit(lambda p, l: bk.trsm_right_upper(p, l.T)), (panel, tri),
         )
         # diagonal-block rows live contiguously: masked dynamic_slice
         gather_fn, gather_args = (
-            jax.jit(lambda a, i, o: jax.lax.dynamic_slice_in_dim(a, i, v) * o),
+            jit(lambda a, i, o: jax.lax.dynamic_slice_in_dim(a, i, v) * o),
             (Afull, jnp.int32(R - v), own[0]),
         )
         unit = False
     else:
         panel_fn, panel_args = (
-            jax.jit(lambda p, w: bk.panel_lup(p, w, v)), (panel, weights),
+            jit(lambda p, w: bk.panel_lup(p, w, v)), (panel, weights),
         )
         trsm_fn, trsm_args = (
-            jax.jit(lambda l, b: bk.trsm_left_lower(l, b, unit=True)), (tri, R01),
+            jit(lambda l, b: bk.trsm_left_lower(l, b, unit=True)), (tri, R01),
         )
         gather_fn, gather_args = (
-            jax.jit(lambda a, i, o: jnp.take(a, i, axis=0) * o[:, None]),
+            jit(lambda a, i, o: jnp.take(a, i, axis=0) * o[:, None]),
             (Afull, lr, own),
         )
         unit = True
@@ -148,13 +153,13 @@ def profile_primitives(N: int, config, grid=None, repeats: int = 3) -> dict:
         ("panel", panel_fn, panel_args),
         ("trsm", trsm_fn, trsm_args),
         ("schur",
-         jax.jit(lambda a, l, u: bk.schur_update(a, l, u)), (A, L10, R01)),
+         jit(lambda a, l, u: bk.schur_update(a, l, u)), (A, L10, R01)),
         ("fused",
-         jax.jit(lambda a, l00, r01, l10:
+         jit(lambda a, l00, r01, l10:
                  bk.fused_trsm_schur(a, l00, r01, l10, unit=unit)),
          (A, tri, R01, L10)),
         ("gather", gather_fn, gather_args),
-        ("gather_dense", jax.jit(lambda s, a: s @ a), (S, Afull)),
+        ("gather_dense", jit(lambda s, a: s @ a), (S, Afull)),
     ]
     measured = _best_of_interleaved(entries, repeats=repeats)
     timings = {}

@@ -63,7 +63,8 @@ class FactorizationPlan:
         # `+= 1` is a read-modify-write that can drop increments under
         # concurrent executes and skew the re-trace accounting.
         self._count_lock = threading.Lock()
-        self._run = run  # (A: np.ndarray [N, N]) -> (F, rows); set by the builder
+        # (A: np.ndarray [N, N]) -> (F, rows, devices); set by build_*
+        self._run = run
         # Set by the builders for static analysis (repro.analysis.audit):
         # the jitted callable and the abstract input it is traced with.
         self._fn = None
@@ -153,7 +154,7 @@ class FactorizationPlan:
         compute = self.config.compute_dtype
         A_lo = A if compute is None else A.astype(resolve_dtype(compute))
         t0 = time.perf_counter()
-        F, rows = self._run(A_lo)
+        F, rows, devices = self._run(A_lo)
         wall_us = (time.perf_counter() - t0) * 1e6
         with self._count_lock:
             self.execute_count += 1
@@ -172,7 +173,7 @@ class FactorizationPlan:
             strategy=self.config.strategy, backend=self.config.backend,
             kind=self.kind, hotloop=dict(self.hotloop),
             A_ref=A, work_dtype=np.dtype(self.config.dtype),
-            autotune=autotune,
+            autotune=autotune, devices=devices,
         )
 
     def __repr__(self):
@@ -258,8 +259,25 @@ def _resolve_backend(N: int, config: SolverConfig) -> SolverConfig:
     return config
 
 
+def _reject_tpu_float64(config: SolverConfig) -> None:
+    """A float64 factorization cannot run as such on a TPU: without jax x64
+    it would silently run in float32, and the chip has no float64 units.
+    Fail loudly instead, naming the mixed-precision route."""
+    import jax
+
+    if (resolve_dtype(config.effective_compute_dtype).itemsize > 4
+            and jax.default_backend() == "tpu"):
+        raise ValueError(
+            "the TPU has no float64 arithmetic, so a float64 factorization "
+            "would run demoted; factor in float32 and refine instead: "
+            "SolverConfig(dtype='float64', compute_dtype='float32') and "
+            "Factorization.solve(b, refine_tol=...)"
+        )
+
+
 def resolve(N: int, config: SolverConfig) -> SolverConfig:
     """Resolve "auto"/missing-grid/backend configs to concrete choices."""
+    _reject_tpu_float64(config)
     for _ in range(3):
         builder = get_strategy(config.strategy)
         resolver = getattr(builder, "resolve", None)

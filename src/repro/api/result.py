@@ -19,7 +19,7 @@ refinement — residual `r = b - A x` in the working dtype, correction solves
 on the cached low-precision factors — returning a `RefinedSolve` carrying
 the refined solution plus `refinement_iters` / `final_residual` /
 `converged`.  A float64 working dtype is honored by wrapping the refine
-program in `jax.experimental.enable_x64()` (the rest of the library runs
+program in `jax.enable_x64(True)` (the rest of the library runs
 without x64, where jax silently demotes f64).
 """
 
@@ -33,11 +33,11 @@ from dataclasses import dataclass, field
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
 
 from repro.core.cholesky.sequential import chol_reconstruct, chol_solve
 from repro.core.lu.grid import GridConfig
 from repro.core.lu.sequential import permutation_sign, unpack_factors
+from repro.core.precision import full_precision
 
 
 def _psolve(F, rows, b):
@@ -58,20 +58,25 @@ def _pudiag(F, rows):
     return F[rows, jnp.arange(n)]
 
 
+def _program(fn):
+    """A jitted program whose dots run at full float32 precision."""
+    return jax.jit(full_precision(fn))
+
+
 # Single-system jitted programs, shared across instances — plus their vmapped
 # siblings for batched factorizations ([B, N, N] factors, [B, N] pivots).
-_packed_solve = jax.jit(_psolve)
-_packed_solve_batched = jax.jit(jax.vmap(_psolve))
-_packed_reconstruct = jax.jit(_preconstruct)
-_packed_reconstruct_batched = jax.jit(jax.vmap(_preconstruct))
-_packed_u_diag = jax.jit(_pudiag)
-_packed_u_diag_batched = jax.jit(jax.vmap(_pudiag))
+_packed_solve = _program(_psolve)
+_packed_solve_batched = _program(jax.vmap(_psolve))
+_packed_reconstruct = _program(_preconstruct)
+_packed_reconstruct_batched = _program(jax.vmap(_preconstruct))
+_packed_u_diag = _program(_pudiag)
+_packed_u_diag_batched = _program(jax.vmap(_pudiag))
 
 # jitted wrappers over the one implementation in core.cholesky.sequential
-_chol_solve = jax.jit(chol_solve)
-_chol_solve_batched = jax.jit(jax.vmap(chol_solve))
-_chol_reconstruct = jax.jit(chol_reconstruct)
-_chol_reconstruct_batched = jax.jit(jax.vmap(chol_reconstruct))
+_chol_solve = _program(chol_solve)
+_chol_solve_batched = _program(jax.vmap(chol_solve))
+_chol_reconstruct = _program(chol_reconstruct)
+_chol_reconstruct_batched = _program(jax.vmap(chol_reconstruct))
 
 
 # ---------------------------------------------------------------------------
@@ -147,7 +152,7 @@ def _refine_core(F, rows, A, b, tol, max_iters, *, chol: bool):
 def _make_refine(chol: bool, batched: bool):
     core = functools.partial(_refine_core, chol=chol)
     if not batched:
-        return jax.jit(core)
+        return _program(core)
 
     def fn(F, rows, A, b, tol, max_iters):
         # per-lane tol (the serving tier carries one tolerance per request);
@@ -156,7 +161,7 @@ def _make_refine(chol: bool, batched: bool):
             lambda F_, rows_, A_, b_, tol_: core(F_, rows_, A_, b_, tol_, max_iters)
         )(F, rows, A, b, tol)
 
-    return jax.jit(fn)
+    return _program(fn)
 
 
 _refine_lu = _make_refine(chol=False, batched=False)
@@ -221,6 +226,9 @@ class Factorization:
     # (predicted_wall_us / measured_wall_us / wall_residual); None unless the
     # plan came from the calibrated `strategy="auto"` path
     autotune: dict | None = None
+    # the devices that held the factorization program's output shards (one
+    # per shard, replicas included); empty on hand-built results
+    devices: tuple[str, ...] = ()
 
     @property
     def N(self) -> int:
@@ -345,7 +353,7 @@ class Factorization:
         # A float64 working dtype needs x64 enabled around conversion AND the
         # jitted program — without it jax silently demotes to f32 and the
         # "refined to f64 quality" contract would be a lie.
-        ctx = enable_x64() if wd == np.float64 else contextlib.nullcontext()
+        ctx = jax.enable_x64(True) if wd == np.float64 else contextlib.nullcontext()
         with ctx:
             A = jnp.asarray(np.asarray(self.A_ref), dtype=wd)
             bj = jnp.asarray(b, dtype=wd)

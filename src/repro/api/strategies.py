@@ -18,6 +18,12 @@ from repro.api.config import SolverConfig, resolve_dtype
 from repro.api.plan import FactorizationPlan
 from repro.api.registry import register_strategy
 from repro.core.lu.grid import optimize_grid, validate_layout
+from repro.core.precision import full_precision
+
+
+def _devices(out: jax.Array) -> tuple[str, ...]:
+    """The devices holding the program's output shards, in shard order."""
+    return tuple(str(s.device) for s in out.addressable_shards)
 
 
 def _compute_aval(shape: tuple[int, ...], config: SolverConfig):
@@ -72,11 +78,11 @@ def build_sequential(N: int, config: SolverConfig, mesh=None) -> FactorizationPl
             return lu_masked_sequential_batched(A, v=v, backend=backend)
         return lu_masked_sequential(A, v=v, backend=backend)
 
-    fn = jax.jit(_traced)
+    fn = jax.jit(full_precision(_traced))
 
     def run(A):
         F, rows = fn(jnp.asarray(A))
-        return np.asarray(F), np.asarray(rows).astype(np.int64)
+        return np.asarray(F), np.asarray(rows).astype(np.int64), _devices(F)
 
     p._run = run
     p._fn = fn
@@ -123,15 +129,8 @@ def _blocks_shape(N: int, grid) -> tuple[int, int, int, int]:
 
 
 def _shard_map(f, mesh, in_specs, out_specs):
-    """shard_map across jax versions: new jax exposes it at the top level
-    (replication check flag `check_vma`), 0.4.x under jax.experimental
-    (`check_rep`)."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as sm
-
-    return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                         check_vma=False)
 
 
 def _build_shardmap_plan(N: int, config: SolverConfig, mesh=None) -> FactorizationPlan:
@@ -163,7 +162,7 @@ def _build_shardmap_plan(N: int, config: SolverConfig, mesh=None) -> Factorizati
 
     fn = jax.jit(
         _shard_map(
-            _traced,
+            full_precision(_traced),
             mesh=mesh,
             in_specs=P("px", "py", None, None),
             out_specs=(P("px", "py", None, None), P()),
@@ -174,7 +173,7 @@ def _build_shardmap_plan(N: int, config: SolverConfig, mesh=None) -> Factorizati
         blocks = block_cyclic_scatter(A, grid.Px, grid.Py, grid.v)
         Fblocks, rows = fn(blocks)
         F = block_cyclic_gather(np.asarray(Fblocks), N, grid.v)
-        return F, np.asarray(rows).astype(np.int64)
+        return F, np.asarray(rows).astype(np.int64), _devices(Fblocks)
 
     p._run = run
     p._fn = fn
@@ -254,7 +253,7 @@ def build_sequential_chol(N: int, config: SolverConfig, mesh=None) -> Factorizat
             return chol_blocked_sequential_batched(A, v=v, backend=backend)
         return chol_blocked_sequential(A, v=v, backend=backend)
 
-    fn = jax.jit(_traced)
+    fn = jax.jit(full_precision(_traced))
 
     def run(A):
         L = fn(jnp.asarray(A))
@@ -262,8 +261,8 @@ def build_sequential_chol(N: int, config: SolverConfig, mesh=None) -> Factorizat
             rows = np.broadcast_to(
                 np.arange(N, dtype=np.int64), (config.B, N)
             ).copy()
-            return np.asarray(L), rows
-        return np.asarray(L), np.arange(N, dtype=np.int64)
+            return np.asarray(L), rows, _devices(L)
+        return np.asarray(L), np.arange(N, dtype=np.int64), _devices(L)
 
     p._run = run
     p._fn = fn
@@ -315,7 +314,7 @@ def build_cholesky25d(N: int, config: SolverConfig, mesh=None) -> FactorizationP
 
     fn = jax.jit(
         _shard_map(
-            _traced,
+            full_precision(_traced),
             mesh=mesh,
             in_specs=P("px", "py", None, None),
             out_specs=P("px", "py", None, None),
@@ -326,7 +325,7 @@ def build_cholesky25d(N: int, config: SolverConfig, mesh=None) -> FactorizationP
         blocks = block_cyclic_scatter(A, grid.Px, grid.Py, grid.v)
         Fblocks = fn(blocks)
         L = block_cyclic_gather(np.asarray(Fblocks), N, grid.v)
-        return L, np.arange(N, dtype=np.int64)
+        return L, np.arange(N, dtype=np.int64), _devices(Fblocks)
 
     p._run = run
     p._fn = fn

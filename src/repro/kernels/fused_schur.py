@@ -11,10 +11,10 @@ row step consumes that resident tile straight on the MXU.  Pallas's
 pipelined BlockSpec staging double-buffers the A/L10 tiles in VMEM around
 the compute, so the only HBM traffic is the tiles the update itself owns.
 
-The substitution body is the same fp32 forward solve as `trsm.py` (column
-independence makes the fused result bit-compatible with the two-call
-composition), and the update is the same fp32-accumulated `A - L @ U` as
-`schur_update.py` with the whole v-contraction in one block.
+The substitution body is `trsm.forward_solve` (column independence makes
+the fused result bit-compatible with the two-call composition), and the
+update is the same fp32-accumulated `A - L @ U` as `schur_update.py` with
+the whole v-contraction in one block, at full fp32 dot precision.
 """
 
 from __future__ import annotations
@@ -26,58 +26,32 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-
-def _forward_solve(L, B, *, v: int, unit: bool):
-    """Forward substitution L @ X = B (same fp32 body as trsm.py)."""
-
-    def body(r, X):
-        partial = (L[r, :] * (jax.lax.broadcasted_iota(jnp.int32, (v,), 0) < r)) @ X
-        xr = B[r, :] - partial
-        if not unit:
-            xr = xr / L[r, r]
-        return X.at[r, :].set(xr)
-
-    return jax.lax.fori_loop(0, v, body, jnp.zeros_like(B))
+from repro.core.precision import dot_precision
+from repro.kernels.trsm import forward_solve
 
 
 def _kernel(a_ref, l00_ref, r01_ref, l10_ref, o_ref, u_ref, u_acc, *,
-            v: int, unit: bool):
-    i = pl.program_id(1)  # row tile — the fast dimension; column tile is slow
-
-    @pl.when(i == 0)
+            row_axis: int, unit: bool):
+    # Row tiles are the fastest grid axis; (system,) column tile are slower.
+    @pl.when(pl.program_id(row_axis) == 0)
     def _solve():
         # Forward substitution L00 @ U = R01 for this column tile, once per
         # column tile; U stays resident in VMEM for every row step below.
-        X = _forward_solve(l00_ref[...].astype(jnp.float32),
-                           r01_ref[...].astype(jnp.float32), v=v, unit=unit)
+        X = forward_solve(l00_ref[...].astype(jnp.float32),
+                          r01_ref[...].astype(jnp.float32), unit=unit)
         u_acc[...] = X
         u_ref[...] = X.astype(u_ref.dtype)
 
     o_ref[...] = (
         a_ref[...].astype(jnp.float32)
         - jnp.dot(l10_ref[...].astype(jnp.float32), u_acc[...],
-                  preferred_element_type=jnp.float32)
+                  preferred_element_type=jnp.float32,
+                  precision=dot_precision(jnp.float32))
     ).astype(o_ref.dtype)
 
 
-def _batched_kernel(a_ref, l00_ref, r01_ref, l10_ref, o_ref, u_ref, u_acc, *,
-                    v: int, unit: bool):
-    i = pl.program_id(2)  # row tile — fastest; (system, column tile) slower
-
-    @pl.when(i == 0)
-    def _solve():
-        # Once per (system, column tile): this system's triangle solves its
-        # own R01 tile, and U stays VMEM-resident for every row step below.
-        X = _forward_solve(l00_ref[0].astype(jnp.float32),
-                           r01_ref[0].astype(jnp.float32), v=v, unit=unit)
-        u_acc[...] = X
-        u_ref[0] = X.astype(u_ref.dtype)
-
-    o_ref[0] = (
-        a_ref[0].astype(jnp.float32)
-        - jnp.dot(l10_ref[0].astype(jnp.float32), u_acc[...],
-                  preferred_element_type=jnp.float32)
-    ).astype(o_ref.dtype)
+def _vmem(*block):
+    return pl.BlockSpec(*block, memory_space=pltpu.VMEM)
 
 
 def fused_trsm_schur(A, L00, R01, L10, *, bm: int = 128, bc: int = 128,
@@ -93,17 +67,17 @@ def fused_trsm_schur(A, L00, R01, L10, *, bm: int = 128, bc: int = 128,
     assert M % bm == 0 and C % bc == 0
     grid = (C // bc, M // bm)  # column tiles outer, row tiles inner
     return pl.pallas_call(
-        functools.partial(_kernel, v=v, unit=unit),
+        functools.partial(_kernel, row_axis=1, unit=unit),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((bm, bc), lambda j, i: (i, j), memory_space=pltpu.VMEM),
-            pl.BlockSpec((v, v), lambda j, i: (0, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((v, bc), lambda j, i: (0, j), memory_space=pltpu.VMEM),
-            pl.BlockSpec((bm, v), lambda j, i: (i, 0), memory_space=pltpu.VMEM),
+            _vmem((bm, bc), lambda j, i: (i, j)),
+            _vmem((v, v), lambda j, i: (0, 0)),
+            _vmem((v, bc), lambda j, i: (0, j)),
+            _vmem((bm, v), lambda j, i: (i, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((bm, bc), lambda j, i: (i, j), memory_space=pltpu.VMEM),
-            pl.BlockSpec((v, bc), lambda j, i: (0, j), memory_space=pltpu.VMEM),
+            _vmem((bm, bc), lambda j, i: (i, j)),
+            _vmem((v, bc), lambda j, i: (0, j)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((M, C), A.dtype),
@@ -130,23 +104,17 @@ def fused_trsm_schur_batched(A, L00, R01, L10, *, bm: int = 128, bc: int = 128,
     assert M % bm == 0 and C % bc == 0
     grid = (B, C // bc, M // bm)  # row tiles fastest, per (system, column tile)
     return pl.pallas_call(
-        functools.partial(_batched_kernel, v=v, unit=unit),
+        functools.partial(_kernel, row_axis=2, unit=unit),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, bm, bc), lambda b, j, i: (b, i, j),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, v, v), lambda b, j, i: (b, 0, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, v, bc), lambda b, j, i: (b, 0, j),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, bm, v), lambda b, j, i: (b, i, 0),
-                         memory_space=pltpu.VMEM),
+            _vmem((None, bm, bc), lambda b, j, i: (b, i, j)),
+            _vmem((None, v, v), lambda b, j, i: (b, 0, 0)),
+            _vmem((None, v, bc), lambda b, j, i: (b, 0, j)),
+            _vmem((None, bm, v), lambda b, j, i: (b, i, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, bm, bc), lambda b, j, i: (b, i, j),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, v, bc), lambda b, j, i: (b, 0, j),
-                         memory_space=pltpu.VMEM),
+            _vmem((None, bm, bc), lambda b, j, i: (b, i, j)),
+            _vmem((None, v, bc), lambda b, j, i: (b, 0, j)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B, M, C), A.dtype),

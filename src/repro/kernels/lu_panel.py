@@ -2,8 +2,16 @@
 
 One program factorizes an [R, v] panel held entirely in VMEM: v rounds of
 (masked argmax pivot -> scale column -> rank-1 trailing update), with row
-masking instead of swaps (paper §7.3).  R*v stays comfortably inside VMEM
-for tournament panels (R <= 4096, v <= 128 -> <= 2 MB fp32).
+masking instead of swaps (paper §7.3).
+
+The kernel works on the transposed panel [v, R]: panel column k is then
+sublane row k, and the long R axis runs along the 128 lanes, so the panel
+takes v*R*4 bytes of VMEM (2 MiB at R=16384, v=32) instead of the
+4x-padded [R, v] layout.  The wrappers transpose in XLA on the way in and
+out.  Mosaic cannot index a value with the loop counter, so each round
+reads row k of a VMEM scratch ref (`pl.ds`), finds the pivot with a max
+and an iota-min (first maximal entry, as `jnp.argmax`), and extracts the
+pivot's column and entry with iota-masked sums, which are exact.
 
 `lu_panel_batched` factorizes B independent panels from a single launch by
 adding a batch grid dimension — one program per system, same per-panel
@@ -13,59 +21,93 @@ small (the many-small-systems serving workload).
 
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _panel_rounds(F, w, *, v: int):
-    """The v pivot/scale/update rounds on one [R, v] panel in registers."""
-    R = F.shape[0]
-    order0 = jnp.zeros((v,), jnp.int32)
-    ok0 = jnp.zeros((v,), jnp.int32)
+def _kernel(pt_ref, w_ref, ft_ref, order_ref, ok_ref, t_ref):
+    """The v pivot/scale/update rounds on one transposed panel [v, R].
+
+    The rounds run in fp32 regardless of the panel dtype (a no-op for f32
+    panels): bf16/f16 -> f32 is exact, so the pivot choice matches the ref
+    backend's fp32-accumulating masked_lup, and only the final packed
+    factors round back down.
+    """
+    v, R = t_ref.shape
+    t_ref[...] = pt_ref[...].astype(jnp.float32)
+    sub = jax.lax.broadcasted_iota(jnp.int32, (v, 1), 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, R), 1)
+    klane = jax.lax.broadcasted_iota(jnp.int32, (1, v), 1)
 
     def body(k, carry):
-        F, w, order, ok = carry
-        col = jnp.abs(F[:, k]) * w
-        p = jnp.argmax(col).astype(jnp.int32)
-        ok = ok.at[k].set((col[p] > 0.0).astype(jnp.int32))
-        order = order.at[k].set(p)
-        w = w * (1.0 - (jax.lax.broadcasted_iota(jnp.int32, (R,), 0) == p))
-        pivval = F[p, k]
+        w, order, ok = carry
+        colk = t_ref[pl.ds(k, 1), :]  # panel column k, [1, R]
+        cand = jnp.abs(colk) * w
+        best = jnp.max(cand, axis=1, keepdims=True)
+        # First maximal entry; a NaN wins, as in jnp.argmax.
+        hit = (cand == best) | jnp.isnan(cand)
+        p = jnp.min(jnp.where(hit, lane, R), axis=1, keepdims=True)  # [1, 1]
+        at_p = lane == p
+        ok = jnp.where(klane == k, (best > 0.0).astype(jnp.int32), ok)
+        order = jnp.where(klane == k, p, order)
+        w = w * (1.0 - at_p.astype(jnp.float32))
+        pivval = jnp.sum(jnp.where(at_p, colk, 0.0), axis=1, keepdims=True)
         safe = jnp.where(jnp.abs(pivval) > 0.0, pivval, 1.0)
         active = w > 0.0
-        mult = jnp.where(active, F[:, k] / safe, F[:, k])
-        F = F.at[:, k].set(mult)
-        colmask = (jax.lax.broadcasted_iota(jnp.int32, (v,), 0) > k).astype(F.dtype)
-        F = F - jnp.outer(jnp.where(active, mult, 0.0), F[p, :] * colmask)
-        return F, w, order, ok
+        mult = jnp.where(active, colk / safe, colk)
+        t_ref[pl.ds(k, 1), :] = mult
+        T = t_ref[...]
+        # Pivot row p over the trailing columns (> k), as a [v, 1] column.
+        prow = jnp.sum(jnp.where(at_p, T, 0.0), axis=1, keepdims=True)
+        prow = prow * (sub > k).astype(jnp.float32)
+        t_ref[...] = T - prow * jnp.where(active, mult, 0.0)
+        return w, order, ok
 
-    return jax.lax.fori_loop(0, v, body, (F, w, order0, ok0))
-
-
-def _kernel(panel_ref, w_ref, f_ref, order_ref, ok_ref, *, v: int):
-    # The rounds run in fp32 regardless of the panel dtype (a no-op for f32
-    # panels): bf16/f16 -> f32 is exact, so the argmax pivot choice matches
-    # the ref backend's fp32-accumulating masked_lup bit-for-bit, and only
-    # the final packed factors round back down.
-    F, _, order, ok = _panel_rounds(
-        panel_ref[...].astype(jnp.float32), w_ref[...].astype(jnp.float32), v=v
+    zeros = jnp.zeros((1, v), jnp.int32)
+    _, order, ok = jax.lax.fori_loop(
+        0, v, body, (w_ref[...].astype(jnp.float32), zeros, zeros)
     )
-    f_ref[...] = F.astype(f_ref.dtype)
+    ft_ref[...] = t_ref[...].astype(ft_ref.dtype)
     order_ref[...] = order
     ok_ref[...] = ok
 
 
-def _batched_kernel(panel_ref, w_ref, f_ref, order_ref, ok_ref, *, v: int):
-    F, _, order, ok = _panel_rounds(
-        panel_ref[0].astype(jnp.float32), w_ref[0].astype(jnp.float32), v=v
-    )
-    f_ref[0] = F.astype(f_ref.dtype)
-    order_ref[0] = order
-    ok_ref[0] = ok
+def _vmem_limit(v: int, R: int) -> int:
+    """Scoped VMEM for one [v, R] panel program: the double-buffered in/out
+    blocks, the fp32 scratch and a few fp32 panel-sized temporaries, within
+    the 128 MiB of a v5e core."""
+    return min(100 * 2**20, max(32 * 2**20, 12 * v * R * 4))
+
+
+def _call(pt, w, batch: int | None, *, interpret: bool):
+    v, R = pt.shape[-2:]
+    lead = () if batch is None else (batch,)
+    bdim = () if batch is None else (None,)
+
+    def spec(*tail):
+        if batch is None:
+            return pl.BlockSpec(tail, lambda: (0,) * len(tail),
+                                memory_space=pltpu.VMEM)
+        return pl.BlockSpec(bdim + tail, lambda b: (b,) + (0,) * len(tail),
+                            memory_space=pltpu.VMEM)
+
+    return pl.pallas_call(
+        _kernel,
+        grid=lead,
+        in_specs=[spec(v, R), spec(1, R)],
+        out_specs=[spec(v, R), spec(1, v), spec(1, v)],
+        out_shape=[
+            jax.ShapeDtypeStruct(lead + (v, R), pt.dtype),
+            jax.ShapeDtypeStruct(lead + (1, v), jnp.int32),
+            jax.ShapeDtypeStruct(lead + (1, v), jnp.int32),
+        ],
+        scratch_shapes=[pltpu.VMEM((v, R), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_vmem_limit(v, R)),
+        interpret=interpret,
+    )(pt, w)
 
 
 def lu_panel(panel, weights, *, interpret: bool = False):
@@ -74,25 +116,9 @@ def lu_panel(panel, weights, *, interpret: bool = False):
     Returns (F [R, v] packed factors, order [v] pivot rows, ok [v] validity).
     """
     R, v = panel.shape
-    return pl.pallas_call(
-        functools.partial(_kernel, v=v),
-        grid=(1,),
-        in_specs=[
-            pl.BlockSpec((R, v), lambda i: (0, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((R,), lambda i: (0,), memory_space=pltpu.VMEM),
-        ],
-        out_specs=[
-            pl.BlockSpec((R, v), lambda i: (0, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((v,), lambda i: (0,), memory_space=pltpu.VMEM),
-            pl.BlockSpec((v,), lambda i: (0,), memory_space=pltpu.VMEM),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((R, v), panel.dtype),
-            jax.ShapeDtypeStruct((v,), jnp.int32),
-            jax.ShapeDtypeStruct((v,), jnp.int32),
-        ],
-        interpret=interpret,
-    )(panel, weights)
+    ft, order, ok = _call(panel.T, weights.reshape(1, R), None,
+                          interpret=interpret)
+    return ft.T, order.reshape(v), ok.reshape(v)
 
 
 def lu_panel_batched(panel, weights, *, interpret: bool = False):
@@ -102,22 +128,6 @@ def lu_panel_batched(panel, weights, *, interpret: bool = False):
     single kernel launch.  Returns (F [B, R, v], order [B, v], ok [B, v]).
     """
     B, R, v = panel.shape
-    return pl.pallas_call(
-        functools.partial(_batched_kernel, v=v),
-        grid=(B,),
-        in_specs=[
-            pl.BlockSpec((1, R, v), lambda b: (b, 0, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, R), lambda b: (b, 0), memory_space=pltpu.VMEM),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, R, v), lambda b: (b, 0, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, v), lambda b: (b, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, v), lambda b: (b, 0), memory_space=pltpu.VMEM),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B, R, v), panel.dtype),
-            jax.ShapeDtypeStruct((B, v), jnp.int32),
-            jax.ShapeDtypeStruct((B, v), jnp.int32),
-        ],
-        interpret=interpret,
-    )(panel, weights)
+    ft, order, ok = _call(jnp.swapaxes(panel, 1, 2), weights.reshape(B, 1, R),
+                          B, interpret=interpret)
+    return jnp.swapaxes(ft, 1, 2), order.reshape(B, v), ok.reshape(B, v)

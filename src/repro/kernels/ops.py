@@ -1,14 +1,17 @@
 """Public jit'd wrappers for the Pallas kernels.
 
-On CPU (this container) the kernels execute in interpret mode — the kernel
-body runs in Python for correctness validation; on TPU they compile to
-Mosaic.  `interpret=None` auto-detects.
+On a TPU the kernels compile with Mosaic; on any other platform they run
+in interpret mode, which checks the kernel bodies' results but says
+nothing about their speed.  `interpret=None` picks by platform.
 
 Block sizes are auto-fit before jit: each requested tile (`bm`/`bn`/`bk`/
 `br`/`bc`) is shrunk to the largest divisor of its array dimension that does
-not exceed it, so a direct `ops.schur_update` / `ops.trsm_*` call on a
-matrix smaller (or merely not a multiple) of the 128/256 defaults works
-instead of tripping the kernels' exact-cover assertions.
+not exceed it and that Mosaic can tile — a multiple of 128 on the lane
+(last) axis of a block, of 8 (16 for 2-byte dtypes) on the sublane axis —
+or else the whole dimension, which Mosaic always accepts.  So a direct
+`ops.schur_update` / `ops.trsm_*` call on a matrix smaller (or merely not a
+multiple) of the 128/256 defaults works instead of tripping the kernels'
+exact-cover assertions.
 """
 
 from __future__ import annotations
@@ -32,12 +35,20 @@ def _interp(flag):
     return jax.default_backend() != "tpu"
 
 
-def _fit(block: int, dim: int) -> int:
-    """Largest tile <= min(block, dim) dividing dim (grids need exact cover)."""
-    for d in range(min(block, dim), 0, -1):
+LANE = 128
+
+
+def _sublane(x) -> int:
+    return 8 * (4 // x.dtype.itemsize)
+
+
+def _fit(block: int, dim: int, align: int) -> int:
+    """Largest tile <= min(block, dim) dividing dim (grids need exact cover)
+    and a multiple of `align`; the whole dim when no such tile exists."""
+    for d in range(min(block, dim) // align * align, 0, -align):
         if dim % d == 0:
             return d
-    return 1
+    return dim
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "bk", "interpret"))
@@ -48,8 +59,8 @@ def _schur_update(A, L, U, bm, bn, bk, interpret):
 def schur_update(A, L, U, bm=128, bn=128, bk=128, interpret=None):
     M, N = A.shape
     K = L.shape[1]
-    return _schur_update(A, L, U, _fit(bm, M), _fit(bn, N), _fit(bk, K),
-                         _interp(interpret))
+    return _schur_update(A, L, U, _fit(bm, M, _sublane(A)), _fit(bn, N, LANE),
+                         _fit(bk, K, LANE), _interp(interpret))
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -68,7 +79,8 @@ def _trsm_right_upper(B, U, br, interpret):
 
 
 def trsm_right_upper(B, U, br=256, interpret=None):
-    return _trsm_right_upper(B, U, _fit(br, B.shape[0]), _interp(interpret))
+    return _trsm_right_upper(B, U, _fit(br, B.shape[0], _sublane(B)),
+                             _interp(interpret))
 
 
 @functools.partial(jax.jit, static_argnames=("bc", "unit", "interpret"))
@@ -77,7 +89,8 @@ def _trsm_left_lower(L, B, bc, unit, interpret):
 
 
 def trsm_left_lower(L, B, bc=256, unit=True, interpret=None):
-    return _trsm_left_lower(L, B, _fit(bc, B.shape[1]), unit, _interp(interpret))
+    return _trsm_left_lower(L, B, _fit(bc, B.shape[1], LANE), unit,
+                            _interp(interpret))
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "bc", "unit", "interpret"))
@@ -92,8 +105,8 @@ def fused_trsm_schur(A, L00, R01, L10, bm=128, bc=128, unit=True, interpret=None
     Returns (A_new, U01) — see `repro.kernels.fused_schur`.
     """
     M, C = A.shape
-    return _fused_trsm_schur(A, L00, R01, L10, _fit(bm, M), _fit(bc, C), unit,
-                             _interp(interpret))
+    return _fused_trsm_schur(A, L00, R01, L10, _fit(bm, M, _sublane(A)),
+                             _fit(bc, C, LANE), unit, _interp(interpret))
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -112,7 +125,7 @@ def _trsm_right_upper_batched(B, U, br, interpret):
 
 
 def trsm_right_upper_batched(B, U, br=256, interpret=None):
-    return _trsm_right_upper_batched(B, U, _fit(br, B.shape[1]),
+    return _trsm_right_upper_batched(B, U, _fit(br, B.shape[1], _sublane(B)),
                                      _interp(interpret))
 
 
@@ -123,7 +136,7 @@ def _trsm_left_lower_batched(L, B, bc, unit, interpret):
 
 
 def trsm_left_lower_batched(L, B, bc=256, unit=True, interpret=None):
-    return _trsm_left_lower_batched(L, B, _fit(bc, B.shape[2]), unit,
+    return _trsm_left_lower_batched(L, B, _fit(bc, B.shape[2], LANE), unit,
                                     _interp(interpret))
 
 
@@ -136,7 +149,8 @@ def _schur_update_batched(A, L, U, bm, bn, bk, interpret):
 def schur_update_batched(A, L, U, bm=128, bn=128, bk=128, interpret=None):
     _, M, N = A.shape
     K = L.shape[2]
-    return _schur_update_batched(A, L, U, _fit(bm, M), _fit(bn, N), _fit(bk, K),
+    return _schur_update_batched(A, L, U, _fit(bm, M, _sublane(A)),
+                                 _fit(bn, N, LANE), _fit(bk, K, LANE),
                                  _interp(interpret))
 
 
@@ -153,8 +167,8 @@ def fused_trsm_schur_batched(A, L00, R01, L10, bm=128, bc=128, unit=True,
     Returns (A_new, U01) with leading batch axes — see `repro.kernels.fused_schur`.
     """
     _, M, C = A.shape
-    return _fused_trsm_schur_batched(A, L00, R01, L10, _fit(bm, M), _fit(bc, C),
-                                     unit, _interp(interpret))
+    return _fused_trsm_schur_batched(A, L00, R01, L10, _fit(bm, M, _sublane(A)),
+                                     _fit(bc, C, LANE), unit, _interp(interpret))
 
 
 @functools.partial(

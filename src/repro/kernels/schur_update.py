@@ -4,7 +4,7 @@ The rank-v trailing update dominates LU FLOPs.  MXU-aligned tiling: (bm, bn)
 output tiles with a (bm, bk)x(bk, bn) accumulation loop over the contraction
 as the fastest grid dimension; the fp32 accumulator lives in VMEM scratch
 across the k-steps (HBM -> VMEM -> MXU staging is explicit in the
-BlockSpecs).
+BlockSpecs).  fp32 operands multiply at full fp32 dot precision.
 """
 
 from __future__ import annotations
@@ -16,37 +16,28 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core.precision import dot_precision
 
-def _kernel(a_ref, l_ref, u_ref, o_ref, acc_ref, *, nk: int):
-    k = pl.program_id(2)
+
+def _kernel(a_ref, l_ref, u_ref, o_ref, acc_ref, *, k_axis: int):
+    k = pl.program_id(k_axis)
 
     @pl.when(k == 0)
     def _():
         acc_ref[...] = a_ref[...].astype(jnp.float32)
 
     acc_ref[...] -= jnp.dot(
-        l_ref[...], u_ref[...], preferred_element_type=jnp.float32
+        l_ref[...], u_ref[...], preferred_element_type=jnp.float32,
+        precision=dot_precision(l_ref.dtype),
     )
 
-    @pl.when(k == nk - 1)
+    @pl.when(k == pl.num_programs(k_axis) - 1)
     def _():
         o_ref[...] = acc_ref[...].astype(o_ref.dtype)
 
 
-def _batched_kernel(a_ref, l_ref, u_ref, o_ref, acc_ref, *, nk: int):
-    k = pl.program_id(3)
-
-    @pl.when(k == 0)
-    def _():
-        acc_ref[...] = a_ref[0].astype(jnp.float32)
-
-    acc_ref[...] -= jnp.dot(
-        l_ref[0], u_ref[0], preferred_element_type=jnp.float32
-    )
-
-    @pl.when(k == nk - 1)
-    def _():
-        o_ref[0] = acc_ref[...].astype(o_ref.dtype)
+def _vmem(*block):
+    return pl.BlockSpec(*block, memory_space=pltpu.VMEM)
 
 
 def schur_update(A, L, U, *, bm: int = 128, bn: int = 128, bk: int = 128,
@@ -58,14 +49,14 @@ def schur_update(A, L, U, *, bm: int = 128, bn: int = 128, bk: int = 128,
     assert M % bm == 0 and N % bn == 0 and K % bk == 0
     grid = (M // bm, N // bn, K // bk)
     return pl.pallas_call(
-        functools.partial(_kernel, nk=grid[2]),
+        functools.partial(_kernel, k_axis=2),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((bm, bn), lambda i, j, k: (i, j), memory_space=pltpu.VMEM),
-            pl.BlockSpec((bm, bk), lambda i, j, k: (i, k), memory_space=pltpu.VMEM),
-            pl.BlockSpec((bk, bn), lambda i, j, k: (k, j), memory_space=pltpu.VMEM),
+            _vmem((bm, bn), lambda i, j, k: (i, j)),
+            _vmem((bm, bk), lambda i, j, k: (i, k)),
+            _vmem((bk, bn), lambda i, j, k: (k, j)),
         ],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j), memory_space=pltpu.VMEM),
+        out_specs=_vmem((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), A.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
@@ -87,18 +78,14 @@ def schur_update_batched(A, L, U, *, bm: int = 128, bn: int = 128, bk: int = 128
     assert M % bm == 0 and N % bn == 0 and K % bk == 0
     grid = (B, M // bm, N // bn, K // bk)
     return pl.pallas_call(
-        functools.partial(_batched_kernel, nk=grid[3]),
+        functools.partial(_kernel, k_axis=3),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, bm, bn), lambda b, i, j, k: (b, i, j),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, bm, bk), lambda b, i, j, k: (b, i, k),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, bk, bn), lambda b, i, j, k: (b, k, j),
-                         memory_space=pltpu.VMEM),
+            _vmem((None, bm, bn), lambda b, i, j, k: (b, i, j)),
+            _vmem((None, bm, bk), lambda b, i, j, k: (b, i, k)),
+            _vmem((None, bk, bn), lambda b, i, j, k: (b, k, j)),
         ],
-        out_specs=pl.BlockSpec((1, bm, bn), lambda b, i, j, k: (b, i, j),
-                               memory_space=pltpu.VMEM),
+        out_specs=_vmem((None, bm, bn), lambda b, i, j, k: (b, i, j)),
         out_shape=jax.ShapeDtypeStruct((B, M, N), A.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,

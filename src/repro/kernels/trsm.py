@@ -4,8 +4,15 @@ trsm_right_upper:  X = B @ U^-1   (L10 computation; U upper-triangular)
 trsm_left_lower:   X = L^-1 @ B   (U01 computation; L unit-lower)
 
 The v x v triangle sits in VMEM; the long dimension is tiled by the grid.
-Inside a tile the solve is a fori over the v columns/rows (forward
-substitution) — v is the paper's blocking parameter (MXU-sized, <= 256).
+Inside a tile the solve is a fori over the v rows (forward substitution) —
+v is the paper's blocking parameter (MXU-sized, <= 256).  Both solves run
+the one row-oriented `forward_solve`: X U = B is U^T X^T = B^T, so the
+right-upper kernel transposes its tile, the triangle and the result inside
+VMEM.
+
+Mosaic cannot index a value with the loop counter, so row i of the
+right-hand side and of the triangle are read with iota-masked sums (exact:
+one nonzero term), and row i of X is written with an iota select.
 
 The `*_batched` variants solve B independent systems from one launch by
 prepending a batch grid dimension — one (b, tile) program per tile, each
@@ -22,57 +29,40 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _right_upper_solve(B, U, *, v: int):
-    """Forward substitution for X U = B, fp32 in/out."""
-
-    def body(j, X):
-        # X[:, j] = (B[:, j] - X[:, :j] @ U[:j, j]) / U[j, j]
-        partial = X @ (U[:, j] * (jax.lax.broadcasted_iota(jnp.int32, (v,), 0) < j))
-        xj = (B[:, j] - partial) / U[j, j]
-        return X.at[:, j].set(xj)
-
-    return jax.lax.fori_loop(0, v, body, jnp.zeros_like(B))
-
-
-def _left_lower_solve(L, B, *, v: int, unit: bool):
-    """Forward substitution for L X = B, fp32 in/out."""
+def forward_solve(L, B, *, unit: bool):
+    """Forward substitution L X = B on fp32 values: L [v, v] lower, B [v, C]."""
+    v = L.shape[0]
+    sub = jax.lax.broadcasted_iota(jnp.int32, (v, 1), 0)
+    klane = jax.lax.broadcasted_iota(jnp.int32, (1, v), 1)
+    LT = L.T  # column i of LT is row i of L, laid out along the sublanes
 
     def body(i, X):
-        partial = (L[i, :] * (jax.lax.broadcasted_iota(jnp.int32, (v,), 0) < i)) @ X
-        xi = B[i, :] - partial
+        li = jnp.sum(jnp.where(klane == i, LT, 0.0), axis=1, keepdims=True)
+        # X rows >= i are still zero, so only the solved rows contribute.
+        partial = jnp.sum(jnp.where(sub < i, li, 0.0) * X, axis=0,
+                          keepdims=True)
+        xi = jnp.sum(jnp.where(sub == i, B, 0.0), axis=0, keepdims=True) - partial
         if not unit:
-            xi = xi / L[i, i]
-        return X.at[i, :].set(xi)
+            xi = xi / jnp.sum(jnp.where(sub == i, li, 0.0), axis=0, keepdims=True)
+        return jnp.where(sub == i, xi, X)
 
     return jax.lax.fori_loop(0, v, body, jnp.zeros_like(B))
 
 
-def _right_upper_kernel(b_ref, u_ref, x_ref, *, v: int):
-    X = _right_upper_solve(
-        b_ref[...].astype(jnp.float32), u_ref[...].astype(jnp.float32), v=v
-    )
+def _right_upper_kernel(b_ref, u_ref, x_ref):
+    XT = forward_solve(u_ref[...].astype(jnp.float32).T,
+                       b_ref[...].astype(jnp.float32).T, unit=False)
+    x_ref[...] = XT.T.astype(x_ref.dtype)
+
+
+def _left_lower_kernel(l_ref, b_ref, x_ref, *, unit: bool):
+    X = forward_solve(l_ref[...].astype(jnp.float32),
+                      b_ref[...].astype(jnp.float32), unit=unit)
     x_ref[...] = X.astype(x_ref.dtype)
 
 
-def _right_upper_batched_kernel(b_ref, u_ref, x_ref, *, v: int):
-    X = _right_upper_solve(
-        b_ref[0].astype(jnp.float32), u_ref[0].astype(jnp.float32), v=v
-    )
-    x_ref[0] = X.astype(x_ref.dtype)
-
-
-def _left_lower_kernel(l_ref, b_ref, x_ref, *, v: int, unit: bool):
-    X = _left_lower_solve(
-        l_ref[...].astype(jnp.float32), b_ref[...].astype(jnp.float32), v=v, unit=unit
-    )
-    x_ref[...] = X.astype(x_ref.dtype)
-
-
-def _left_lower_batched_kernel(l_ref, b_ref, x_ref, *, v: int, unit: bool):
-    X = _left_lower_solve(
-        l_ref[0].astype(jnp.float32), b_ref[0].astype(jnp.float32), v=v, unit=unit
-    )
-    x_ref[0] = X.astype(x_ref.dtype)
+def _vmem(*block):
+    return pl.BlockSpec(*block, memory_space=pltpu.VMEM)
 
 
 def trsm_right_upper(B, U, *, br: int = 256, interpret: bool = False):
@@ -81,13 +71,11 @@ def trsm_right_upper(B, U, *, br: int = 256, interpret: bool = False):
     br = min(br, R)
     assert R % br == 0
     return pl.pallas_call(
-        functools.partial(_right_upper_kernel, v=v),
+        _right_upper_kernel,
         grid=(R // br,),
-        in_specs=[
-            pl.BlockSpec((br, v), lambda i: (i, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((v, v), lambda i: (0, 0), memory_space=pltpu.VMEM),
-        ],
-        out_specs=pl.BlockSpec((br, v), lambda i: (i, 0), memory_space=pltpu.VMEM),
+        in_specs=[_vmem((br, v), lambda i: (i, 0)),
+                  _vmem((v, v), lambda i: (0, 0))],
+        out_specs=_vmem((br, v), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((R, v), B.dtype),
         interpret=interpret,
     )(B, U)
@@ -99,14 +87,11 @@ def trsm_right_upper_batched(B, U, *, br: int = 256, interpret: bool = False):
     br = min(br, R)
     assert R % br == 0
     return pl.pallas_call(
-        functools.partial(_right_upper_batched_kernel, v=v),
+        _right_upper_kernel,
         grid=(Bb, R // br),
-        in_specs=[
-            pl.BlockSpec((1, br, v), lambda b, i: (b, i, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, v, v), lambda b, i: (b, 0, 0), memory_space=pltpu.VMEM),
-        ],
-        out_specs=pl.BlockSpec((1, br, v), lambda b, i: (b, i, 0),
-                               memory_space=pltpu.VMEM),
+        in_specs=[_vmem((None, br, v), lambda b, i: (b, i, 0)),
+                  _vmem((None, v, v), lambda b, i: (b, 0, 0))],
+        out_specs=_vmem((None, br, v), lambda b, i: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((Bb, R, v), B.dtype),
         interpret=interpret,
     )(B, U)
@@ -118,13 +103,11 @@ def trsm_left_lower(L, B, *, bc: int = 256, unit: bool = True, interpret: bool =
     bc = min(bc, C)
     assert C % bc == 0
     return pl.pallas_call(
-        functools.partial(_left_lower_kernel, v=v, unit=unit),
+        functools.partial(_left_lower_kernel, unit=unit),
         grid=(C // bc,),
-        in_specs=[
-            pl.BlockSpec((v, v), lambda i: (0, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((v, bc), lambda i: (0, i), memory_space=pltpu.VMEM),
-        ],
-        out_specs=pl.BlockSpec((v, bc), lambda i: (0, i), memory_space=pltpu.VMEM),
+        in_specs=[_vmem((v, v), lambda i: (0, 0)),
+                  _vmem((v, bc), lambda i: (0, i))],
+        out_specs=_vmem((v, bc), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((v, C), B.dtype),
         interpret=interpret,
     )(L, B)
@@ -137,14 +120,11 @@ def trsm_left_lower_batched(L, B, *, bc: int = 256, unit: bool = True,
     bc = min(bc, C)
     assert C % bc == 0
     return pl.pallas_call(
-        functools.partial(_left_lower_batched_kernel, v=v, unit=unit),
+        functools.partial(_left_lower_kernel, unit=unit),
         grid=(Bb, C // bc),
-        in_specs=[
-            pl.BlockSpec((1, v, v), lambda b, i: (b, 0, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, v, bc), lambda b, i: (b, 0, i), memory_space=pltpu.VMEM),
-        ],
-        out_specs=pl.BlockSpec((1, v, bc), lambda b, i: (b, 0, i),
-                               memory_space=pltpu.VMEM),
+        in_specs=[_vmem((None, v, v), lambda b, i: (b, 0, 0)),
+                  _vmem((None, v, bc), lambda b, i: (b, 0, i))],
+        out_specs=_vmem((None, v, bc), lambda b, i: (b, 0, i)),
         out_shape=jax.ShapeDtypeStruct((Bb, v, C), B.dtype),
         interpret=interpret,
     )(L, B)

@@ -33,11 +33,15 @@ for backend, fact in facts.items():
     err = np.abs(np.asarray(fact.reconstruct()) - A).max()
     assert err < 1e-4, (backend, err)
     assert sorted(fact.rows.tolist()) == list(range(N)), backend
+    # one output shard per device of the 2x1x1 mesh
+    assert len(set(fact.devices)) == 2, (backend, fact.devices)
 
-# Windowed-vs-flat bit parity with *real* collectives inside the lax.switch
+# Windowed-vs-flat parity with *real* collectives inside the lax.switch
 # bucket bodies: Px=2 exercises the tournament ppermute and the (px, pz)
 # gather psums across genuinely distinct devices per branch — the case the
-# single-device sweep in tests/test_hotloop.py cannot reach.
+# single-device sweep in tests/test_hotloop.py cannot reach.  Pivots match
+# exactly; the factors of the two programs agree to N ulps of the largest
+# entry (XLA may fuse and order their reductions differently).
 G = rng.standard_normal((N, N)).astype(np.float32)
 A_spd = (G @ G.T / N + np.eye(N, dtype=np.float32))
 for strategy, Ain, pivot in [("conflux", A, "tournament"),
@@ -49,8 +53,9 @@ for strategy, Ain, pivot in [("conflux", A, "tournament"),
         hl_facts[hl] = plan(N, cfg).execute(Ain)
     w, f = hl_facts["windowed"], hl_facts["flat"]
     assert np.array_equal(w.rows, f.rows), (strategy, pivot, "pivot order diverged")
-    assert np.array_equal(np.asarray(w.F), np.asarray(f.F)), (
-        strategy, pivot, "factors diverged", np.abs(w.F - f.F).max())
+    tol = N * np.finfo(f.F.dtype).eps * np.abs(f.F).max()
+    assert np.abs(w.F - f.F).max() <= tol, (
+        strategy, pivot, "factors diverged", np.abs(w.F - f.F).max(), tol)
     err = np.abs(np.asarray(w.reconstruct()) - Ain).max()
     assert err < 1e-4, (strategy, pivot, err)
 print("ALL-OK")

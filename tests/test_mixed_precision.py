@@ -11,6 +11,7 @@ serving refinement plumbing, and the byte-accurate comm report.
 
 import warnings
 
+import jax
 import numpy as np
 import pytest
 
@@ -63,6 +64,21 @@ class TestConfigValidation:
         cfg = SolverConfig(dtype="float64", compute_dtype="bfloat16")
         assert cfg.effective_compute_dtype == "bfloat16"
         assert SolverConfig(dtype="float64").effective_compute_dtype == "float64"
+
+    @pytest.mark.parametrize("compute, ok", [(None, False), ("float32", True)])
+    def test_tpu_rejects_float64_factorization(self, monkeypatch, compute, ok):
+        """On a TPU an f64 factorization fails loudly; f32 factors of an f64
+        working matrix (the refinement route) still resolve."""
+        from repro.api import resolve
+
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        cfg = SolverConfig(strategy="sequential", dtype="float64",
+                           compute_dtype=compute)
+        if ok:
+            assert resolve(64, cfg).effective_compute_dtype == "float32"
+        else:
+            with pytest.raises(ValueError, match="no float64"):
+                resolve(64, cfg)
 
     def test_resolve_dtype_knows_bfloat16(self):
         dt = resolve_dtype("bfloat16")
@@ -356,9 +372,7 @@ class TestCommReportBytes:
         elems, nbytes = self._total_row(rep32)
         assert nbytes == pytest.approx(4 * elems)  # f32 over the wire
 
-        from jax.experimental import enable_x64
-
-        with enable_x64():  # a genuine f64 plan (demoted to f32 otherwise)
+        with jax.enable_x64(True):  # a genuine f64 plan (demoted to f32 otherwise)
             rep64 = plan(n, SolverConfig(strategy="conflux", grid=grid,
                                          dtype="float64",
                                          backend="ref")).execute(A).comm_report()

@@ -1,5 +1,6 @@
 """Unified plan/execute solver API: registry, plan cache, Factorization."""
 
+import jax
 import numpy as np
 import pytest
 
@@ -173,6 +174,7 @@ class TestFactorizationCorrectness:
         fact = factor(A, SolverConfig(strategy="sequential"))
         x = np.asarray(fact.solve(b))
         assert np.abs(A @ x - b).max() < 5e-4
+        assert fact.devices == (str(jax.devices()[0]),)
         s, ld = fact.slogdet()
         s_np, ld_np = np.linalg.slogdet(A.astype(np.float64))
         assert float(s) == pytest.approx(s_np)

@@ -1,0 +1,106 @@
+"""Compile the solver's Pallas kernels for a TPU v5e, without the chip.
+
+Off the TPU the kernels run in interpret mode, which accepts code that
+Mosaic refuses: indexing a value with a loop counter, tiles that are not
+(8, 128)-aligned, more VMEM than a core has.  These tests compile every
+kernel — single-system and batched, f32 and bf16 — for one chip of a
+described v5e:2x2 topology, at the widths `chip_smoke.py` runs (panels of
+R=16384 rows, v=32; batched slots of 32 systems of n=256), and check that
+the compiled program holds the Mosaic kernel (`tpu_custom_call`).  Two
+more compile the whole in-core LU and Cholesky plans at N=16384 and check
+that they fit the chip's 16 GiB.
+
+The topology is described inside a fixture, never at import: only one
+process may load the TPU library, and the test workers all import this
+file.  The persistent compilation cache is off around these compiles (an
+entry written without a chip cannot be read back).
+"""
+
+from __future__ import annotations
+
+import os
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from repro.kernels import ops
+
+N = 16384  # in-core panel height / matrix size of chip_smoke.py
+V = 32  # panel width
+B, n = 32, 256  # largest serving slot: 32 systems of n=256
+HBM_BYTES = 16 * 2**30  # one v5e chip
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+# kernel -> (ops function, operand shapes); every call runs interpret=False
+KERNELS = {
+    "lu_panel": (ops.lu_panel, [(N, V), (N,)]),
+    "lu_panel_batched": (ops.lu_panel_batched, [(B, n, V), (B, n)]),
+    "chol_panel": (ops.chol_panel, [(V, V)]),
+    "chol_panel_batched": (ops.chol_panel_batched, [(B, V, V)]),
+    "trsm_right_upper": (ops.trsm_right_upper, [(N, V), (V, V)]),
+    "trsm_right_upper_batched": (ops.trsm_right_upper_batched,
+                                 [(B, n, V), (B, V, V)]),
+    "trsm_left_lower": (ops.trsm_left_lower, [(V, V), (V, N)]),
+    "trsm_left_lower_batched": (ops.trsm_left_lower_batched,
+                                [(B, V, V), (B, V, n)]),
+    "schur_update": (ops.schur_update, [(N, N), (N, V), (V, N)]),
+    "schur_update_batched": (ops.schur_update_batched,
+                             [(B, n, n), (B, n, V), (B, V, n)]),
+    "fused_trsm_schur": (ops.fused_trsm_schur,
+                         [(N, N), (V, V), (V, N), (N, V)]),
+    "fused_trsm_schur_batched": (ops.fused_trsm_schur_batched,
+                                 [(B, n, n), (B, V, V), (B, V, n), (B, n, V)]),
+}
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("kernel", sorted(KERNELS))
+def test_kernel_compiles_for_v5e(one_chip, kernel, dtype):
+    fn, shapes = KERNELS[kernel]
+    avals = [jax.ShapeDtypeStruct(s, jnp.dtype(dtype), sharding=one_chip)
+             for s in shapes]
+    compiled = jax.jit(lambda *a: fn(*a, interpret=False)).lower(*avals).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("strategy", ["sequential", "sequential_chol"])
+def test_in_core_plan_compiles_for_v5e(one_chip, strategy, monkeypatch):
+    """The whole N=16384 pallas plan: Mosaic kernels inside, fits in HBM."""
+    from repro.api import SolverConfig, clear_plan_cache, plan
+
+    # Off the chip the ops wrappers pick interpret mode by platform; the
+    # compile is for the chip, so steer them to Mosaic here.
+    monkeypatch.setattr(ops, "_interp", lambda flag: False)
+    p = plan(N, SolverConfig(strategy=strategy, backend="pallas", v=V))
+    try:
+        aval = jax.ShapeDtypeStruct((N, N), jnp.float32, sharding=one_chip)
+        compiled = p._fn.lower(aval).compile()
+    finally:
+        clear_plan_cache()
+        jax.clear_caches()  # drop the traces made with Mosaic kernels
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes)
+    assert used < HBM_BYTES, f"{used / 2**30:.2f} GiB exceeds one chip"
