@@ -25,6 +25,7 @@ from collections import OrderedDict
 
 import numpy as np
 
+from repro import obs
 from repro.api.config import SolverConfig, resolve_dtype
 from repro.api.registry import get_strategy
 from repro.api.result import Factorization
@@ -128,7 +129,11 @@ class FactorizationPlan:
         """Factorize A with the compiled program (no re-trace).
 
         A is [N, N], or [B, N, N] for a batched plan (`plan((B, N))`)."""
-        A = np.asarray(A)
+        with obs.span(obs.EXECUTE):
+            return self._execute(A)
+
+    def _execute(self, A) -> Factorization:
+        A = obs.to_host(A)
         if A.dtype.kind == "c":
             raise ValueError(
                 f"complex matrices are not supported (plan computes in "
@@ -139,7 +144,7 @@ class FactorizationPlan:
             warnings.warn(
                 f"plan computes in {self.config.dtype}; input {A.dtype} will be "
                 f"downcast (set SolverConfig.dtype to keep precision)",
-                stacklevel=2,
+                stacklevel=3,
             )
         A = A.astype(self.config.dtype, copy=False)
         want = (self.N, self.N) if self.B is None else (self.B, self.N, self.N)

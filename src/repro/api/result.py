@@ -34,6 +34,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core.cholesky.sequential import chol_reconstruct, chol_solve
 from repro.core.lu.grid import GridConfig
 from repro.core.lu.sequential import permutation_sign, unpack_factors
@@ -270,6 +271,10 @@ class Factorization:
         On a batched factorization `refine_tol` may be a [B] array (one
         tolerance per system).
         """
+        with obs.span(obs.SOLVE):
+            return self._solve(b, refine_tol, max_refine_iters)
+
+    def _solve(self, b, refine_tol, max_refine_iters: int):
         # Inspect the incoming dtype before jnp.asarray: without jax x64 the
         # conversion itself silently demotes float64, which is exactly the
         # downcast we must surface.  Only arrays carry dtype intent — a plain
@@ -294,18 +299,18 @@ class Factorization:
             warnings.warn(
                 f"factors are {self.dtype}; RHS {in_dt.name} will be downcast "
                 f"({hint})",
-                stacklevel=2,
+                stacklevel=3,
             )
         if wd != self.dtype and self.dtype.itemsize < 4:
             # mixed-precision factors narrower than fp32: solve with fp32
             # arithmetic (bf16 triangular substitutions would add solve noise
             # on top of the factor error for no win)
             solve_dt = np.dtype(np.float32)
-            F = jnp.asarray(self.F).astype(solve_dt)
+            F = obs.to_device(self.F).astype(solve_dt)
         else:
             solve_dt = self.dtype
-            F = jnp.asarray(self.F)
-        b = jnp.asarray(b, dtype=solve_dt)
+            F = obs.to_device(self.F)
+        b = obs.to_device(b, dtype=solve_dt)
         if self.batched:
             if b.ndim not in (2, 3) or b.shape[:2] != (self.B, self.N):
                 raise ValueError(
@@ -314,14 +319,14 @@ class Factorization:
                 )
             if self.kind == "cholesky":
                 return _chol_solve_batched(F, b)
-            return _packed_solve_batched(F, jnp.asarray(self.rows), b)
+            return _packed_solve_batched(F, obs.to_device(self.rows), b)
         if b.ndim not in (1, 2) or b.shape[0] != self.N:
             raise ValueError(
                 f"b must be [N] or [N, k] with N={self.N}, got shape {b.shape}"
             )
         if self.kind == "cholesky":
             return _chol_solve(F, b)
-        return _packed_solve(F, jnp.asarray(self.rows), b)
+        return _packed_solve(F, obs.to_device(self.rows), b)
 
     def _solve_refined(self, b, tol, max_iters: int) -> RefinedSolve:
         """Iterative refinement against the retained working-precision A_ref."""
@@ -355,10 +360,10 @@ class Factorization:
         # "refined to f64 quality" contract would be a lie.
         ctx = jax.enable_x64(True) if wd == np.float64 else contextlib.nullcontext()
         with ctx:
-            A = jnp.asarray(np.asarray(self.A_ref), dtype=wd)
-            bj = jnp.asarray(b, dtype=wd)
-            F = jnp.asarray(self.F)
-            rows = jnp.asarray(self.rows)
+            A = obs.to_device(self.A_ref, dtype=wd)
+            bj = obs.to_device(b, dtype=wd)
+            F = obs.to_device(self.F)
+            rows = obs.to_device(self.rows)
             mi = jnp.asarray(int(max_iters), jnp.int32)
             if self.batched:
                 tol_arr = jnp.broadcast_to(
@@ -369,8 +374,7 @@ class Factorization:
                 tol_arr = jnp.asarray(float(tol), dtype=wd)
                 fn = _refine_chol if chol else _refine_lu
             x, it, res, conv = fn(F, rows, A, bj, tol_arr, mi)
-            x, it, res, conv = (np.asarray(v) for v in
-                                jax.block_until_ready((x, it, res, conv)))
+            x, it, res, conv = (obs.to_host(v) for v in (x, it, res, conv))
         if self.batched:
             return RefinedSolve(
                 x=x, refinement_iters=it, final_residual=res, converged=conv

@@ -11,9 +11,9 @@ Heavy modules (the shard_map program) are imported inside the builders so
 from __future__ import annotations
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.api.config import SolverConfig, resolve_dtype
 from repro.api.plan import FactorizationPlan
 from repro.api.registry import register_strategy
@@ -24,6 +24,13 @@ from repro.core.precision import full_precision
 def _devices(out: jax.Array) -> tuple[str, ...]:
     """The devices holding the program's output shards, in shard order."""
     return tuple(str(s.device) for s in out.addressable_shards)
+
+
+def _factor(fn, A):
+    """Upload A, then run the factor program `fn` to ready outputs."""
+    A = obs.to_device(A)
+    with obs.span(obs.FACTOR):
+        return jax.block_until_ready(fn(A))
 
 
 def _compute_aval(shape: tuple[int, ...], config: SolverConfig):
@@ -81,8 +88,8 @@ def build_sequential(N: int, config: SolverConfig, mesh=None) -> FactorizationPl
     fn = jax.jit(full_precision(_traced))
 
     def run(A):
-        F, rows = fn(jnp.asarray(A))
-        return np.asarray(F), np.asarray(rows).astype(np.int64), _devices(F)
+        F, rows = _factor(fn, A)
+        return obs.to_host(F), obs.to_host(rows).astype(np.int64), _devices(F)
 
     p._run = run
     p._fn = fn
@@ -256,13 +263,13 @@ def build_sequential_chol(N: int, config: SolverConfig, mesh=None) -> Factorizat
     fn = jax.jit(full_precision(_traced))
 
     def run(A):
-        L = fn(jnp.asarray(A))
+        L = _factor(fn, A)
         if batched:
             rows = np.broadcast_to(
                 np.arange(N, dtype=np.int64), (config.B, N)
             ).copy()
-            return np.asarray(L), rows, _devices(L)
-        return np.asarray(L), np.arange(N, dtype=np.int64), _devices(L)
+            return obs.to_host(L), rows, _devices(L)
+        return obs.to_host(L), np.arange(N, dtype=np.int64), _devices(L)
 
     p._run = run
     p._fn = fn

@@ -19,6 +19,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
+
 
 @functools.partial(jax.jit, static_argnames=("v",))
 def masked_lup(panel: jax.Array, weights: jax.Array, v: int):
@@ -79,25 +81,29 @@ def lu_masked_sequential(A: jax.Array, v: int = 32, backend: str = "ref"):
     def step(t, carry):
         F, active, rows = carry
         c0 = t * v
-        panel = jax.lax.dynamic_slice(F, (0, c0), (N, v))
-        Fp, order, _ = bk.panel_lup(panel, active, v)
-        F = jax.lax.dynamic_update_slice(F, Fp, (0, c0))
-        rows = jax.lax.dynamic_update_slice(rows, order.astype(jnp.int32), (c0,))
-        piv_onehot = jax.nn.one_hot(order, N, dtype=F.dtype)  # [v, N]
-        active = active * (1.0 - piv_onehot.sum(0))
-        # Trailing update: A11 -= L10 @ U01.
-        colmask = (jnp.arange(N) >= c0 + v).astype(F.dtype)  # [N]
-        L10 = Fp * active[:, None]  # multipliers of still-active rows
-        U00_packed = piv_onehot @ Fp  # [v, v] packed LU of the pivot block
-        L00 = jnp.tril(U00_packed, -1) + jnp.eye(v, dtype=F.dtype)
-        R01 = (piv_onehot @ F) * colmask[None, :]  # pivot rows, trailing cols
-        # Steps 5+6 fused: U01 = L00^-1 R01 and the trailing update in one
-        # backend call (R01 is pre-masked, so U01 comes out masked columnwise).
-        F, U01 = bk.fused_trsm_schur(F, L00, R01, L10 * active[:, None], unit=True)
-        # Write U01 into the pivot rows' trailing columns.
-        F = F * (1.0 - piv_onehot.sum(0)[:, None] * colmask[None, :]) + piv_onehot.T @ (
-            U01 * colmask[None, :]
-        )
+        with obs.scope(obs.LU_PANEL):
+            panel = jax.lax.dynamic_slice(F, (0, c0), (N, v))
+            Fp, order, _ = bk.panel_lup(panel, active, v)
+            F = jax.lax.dynamic_update_slice(F, Fp, (0, c0))
+            rows = jax.lax.dynamic_update_slice(rows, order.astype(jnp.int32), (c0,))
+        with obs.scope(obs.LU_PIVOT_GATHER):
+            piv_onehot = jax.nn.one_hot(order, N, dtype=F.dtype)  # [v, N]
+            active = active * (1.0 - piv_onehot.sum(0))
+            colmask = (jnp.arange(N) >= c0 + v).astype(F.dtype)  # [N]
+            U00_packed = piv_onehot @ Fp  # [v, v] packed LU of the pivot block
+            L00 = jnp.tril(U00_packed, -1) + jnp.eye(v, dtype=F.dtype)
+            R01 = (piv_onehot @ F) * colmask[None, :]  # pivot rows, trailing cols
+        with obs.scope(obs.LU_UPDATE):
+            # Trailing update: A11 -= L10 @ U01.
+            L10 = Fp * active[:, None]  # multipliers of still-active rows
+            # Steps 5+6 fused: U01 = L00^-1 R01 and the trailing update in one
+            # backend call (R01 is pre-masked, so U01 comes out masked columnwise).
+            F, U01 = bk.fused_trsm_schur(F, L00, R01, L10 * active[:, None], unit=True)
+        with obs.scope(obs.LU_PIVOT_WRITEBACK):
+            # Write U01 into the pivot rows' trailing columns.
+            F = F * (1.0 - piv_onehot.sum(0)[:, None] * colmask[None, :]) + piv_onehot.T @ (
+                U01 * colmask[None, :]
+            )
         return (F, active, rows)
 
     init = (A, jnp.ones(N, A.dtype), jnp.zeros(N, jnp.int32))
@@ -128,23 +134,27 @@ def lu_masked_sequential_batched(A: jax.Array, v: int = 32, backend: str = "ref"
     def step(t, carry):
         F, active, rows = carry
         c0 = t * v
-        panel = jax.lax.dynamic_slice(F, (0, 0, c0), (B, N, v))
-        Fp, order, _ = bk.panel_lup_batched(panel, active, v)
-        F = jax.lax.dynamic_update_slice(F, Fp, (0, 0, c0))
-        rows = jax.lax.dynamic_update_slice(rows, order.astype(jnp.int32), (0, c0))
-        piv_onehot = jax.nn.one_hot(order, N, dtype=F.dtype)  # [B, v, N]
-        active = active * (1.0 - piv_onehot.sum(1))
-        colmask = (jnp.arange(N) >= c0 + v).astype(F.dtype)  # [N]
-        L10 = Fp * active[:, :, None]
-        U00_packed = piv_onehot @ Fp  # [B, v, v]
-        L00 = jnp.tril(U00_packed, -1) + jnp.eye(v, dtype=F.dtype)
-        R01 = (piv_onehot @ F) * colmask[None, None, :]
-        F, U01 = bk.fused_trsm_schur_batched(
-            F, L00, R01, L10 * active[:, :, None], unit=True
-        )
-        F = F * (
-            1.0 - piv_onehot.sum(1)[:, :, None] * colmask[None, None, :]
-        ) + jnp.swapaxes(piv_onehot, 1, 2) @ (U01 * colmask[None, None, :])
+        with obs.scope(obs.LU_PANEL):
+            panel = jax.lax.dynamic_slice(F, (0, 0, c0), (B, N, v))
+            Fp, order, _ = bk.panel_lup_batched(panel, active, v)
+            F = jax.lax.dynamic_update_slice(F, Fp, (0, 0, c0))
+            rows = jax.lax.dynamic_update_slice(rows, order.astype(jnp.int32), (0, c0))
+        with obs.scope(obs.LU_PIVOT_GATHER):
+            piv_onehot = jax.nn.one_hot(order, N, dtype=F.dtype)  # [B, v, N]
+            active = active * (1.0 - piv_onehot.sum(1))
+            colmask = (jnp.arange(N) >= c0 + v).astype(F.dtype)  # [N]
+            U00_packed = piv_onehot @ Fp  # [B, v, v]
+            L00 = jnp.tril(U00_packed, -1) + jnp.eye(v, dtype=F.dtype)
+            R01 = (piv_onehot @ F) * colmask[None, None, :]
+        with obs.scope(obs.LU_UPDATE):
+            L10 = Fp * active[:, :, None]
+            F, U01 = bk.fused_trsm_schur_batched(
+                F, L00, R01, L10 * active[:, :, None], unit=True
+            )
+        with obs.scope(obs.LU_PIVOT_WRITEBACK):
+            F = F * (
+                1.0 - piv_onehot.sum(1)[:, :, None] * colmask[None, None, :]
+            ) + jnp.swapaxes(piv_onehot, 1, 2) @ (U01 * colmask[None, None, :])
         return (F, active, rows)
 
     init = (A, jnp.ones((B, N), A.dtype), jnp.zeros((B, N), jnp.int32))

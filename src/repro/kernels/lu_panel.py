@@ -26,6 +26,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro import obs
+
 
 def _kernel(pt_ref, w_ref, ft_ref, order_ref, ok_ref, t_ref):
     """The v pivot/scale/update rounds on one transposed panel [v, R].
@@ -116,9 +118,13 @@ def lu_panel(panel, weights, *, interpret: bool = False):
     Returns (F [R, v] packed factors, order [v] pivot rows, ok [v] validity).
     """
     R, v = panel.shape
-    ft, order, ok = _call(panel.T, weights.reshape(1, R), None,
+    with obs.scope(obs.LU_LAYOUT):
+        pt = panel.T
+    ft, order, ok = _call(pt, weights.reshape(1, R), None,
                           interpret=interpret)
-    return ft.T, order.reshape(v), ok.reshape(v)
+    with obs.scope(obs.LU_LAYOUT):
+        f = ft.T
+    return f, order.reshape(v), ok.reshape(v)
 
 
 def lu_panel_batched(panel, weights, *, interpret: bool = False):
@@ -128,6 +134,9 @@ def lu_panel_batched(panel, weights, *, interpret: bool = False):
     single kernel launch.  Returns (F [B, R, v], order [B, v], ok [B, v]).
     """
     B, R, v = panel.shape
-    ft, order, ok = _call(jnp.swapaxes(panel, 1, 2), weights.reshape(B, 1, R),
-                          B, interpret=interpret)
-    return jnp.swapaxes(ft, 1, 2), order.reshape(B, v), ok.reshape(B, v)
+    with obs.scope(obs.LU_LAYOUT):
+        pt = jnp.swapaxes(panel, 1, 2)
+    ft, order, ok = _call(pt, weights.reshape(B, 1, R), B, interpret=interpret)
+    with obs.scope(obs.LU_LAYOUT):
+        f = jnp.swapaxes(ft, 1, 2)
+    return f, order.reshape(B, v), ok.reshape(B, v)

@@ -37,6 +37,7 @@ timing-flake-free).
 
 from __future__ import annotations
 
+import itertools
 import threading
 import time
 from concurrent.futures import Future
@@ -46,6 +47,7 @@ import numpy as np
 
 from typing import NamedTuple
 
+from repro import obs
 from repro.api import plan
 from repro.serving.metrics import Ring
 from repro.serving.queues import Overloaded, Request, TenantQueues
@@ -109,6 +111,7 @@ class AsyncSolveEngine:
         self._depths = Ring(metrics_window)
         self._fills = Ring(min(metrics_window, 1024))
         self._flushes = 0
+        self._flush_seq = itertools.count()  # id of each flush's trace span
         self._served = 0
         self._failed = 0  # futures completed with the solver's exception
         self._closed = False
@@ -338,16 +341,21 @@ class AsyncSolveEngine:
         active = [r for r in batch if r.future.set_running_or_notify_cancel()]
         if not active:
             return 0
-        systems = [r for r in active if not isinstance(r.prep, _PreparedRHS)]
-        rhs = [r for r in active if isinstance(r.prep, _PreparedRHS)]
-        served = self._serve_group(
-            systems, self._engine._enqueue_prepared,
-            self._engine.flush_systems, self._engine._abort_pending_systems,
-        )
-        served += self._serve_group(
-            rhs, lambda p: self._engine.submit(p.b),
-            self._engine.flush, self._engine._abort_pending_rhs,
-        )
+        now = self._clock()
+        waits = [now - r.t_submit for r in active]
+        with obs.span(obs.SERVE_FLUSH, flush=next(self._flush_seq),
+                      systems=len(active), wait_s_sum=sum(waits),
+                      wait_s_max=max(waits)):
+            systems = [r for r in active if not isinstance(r.prep, _PreparedRHS)]
+            rhs = [r for r in active if isinstance(r.prep, _PreparedRHS)]
+            served = self._serve_group(
+                systems, self._engine._enqueue_prepared,
+                self._engine.flush_systems, self._engine._abort_pending_systems,
+            )
+            served += self._serve_group(
+                rhs, lambda p: self._engine.submit(p.b),
+                self._engine.flush, self._engine._abort_pending_rhs,
+            )
         if served:
             with self._cv:
                 self._flushes += 1
@@ -372,9 +380,10 @@ class AsyncSolveEngine:
                 r.future.set_exception(exc)
             return 0
         done = self._clock()
-        for r, t in zip(group, tickets):
-            r.future.set_result(np.asarray(xs[t]))
-            self._lat_ms.record((done - r.t_submit) * 1e3)
+        with obs.span(obs.SERVE_COMPLETE):
+            for r, t in zip(group, tickets):
+                r.future.set_result(np.asarray(xs[t]))
+                self._lat_ms.record((done - r.t_submit) * 1e3)
         with self._cv:
             for r in group:
                 self._queues.mark_served(r.tenant)

@@ -51,6 +51,7 @@ from typing import NamedTuple
 import jax
 import numpy as np
 
+from repro import obs
 from repro.api import Factorization, SolverConfig, plan, plan_cache_stats
 
 # Floor for the ragged-N power-of-two slot: below this the per-request
@@ -217,7 +218,7 @@ class SolveEngine:
             self._n_solve += len(pending)
             self._n_batched += 1
             self._n_batched_rhs += len(pending)
-        X = np.asarray(X)
+        X = obs.to_host(X)
         return [X[:, j] for j in range(X.shape[1])]
 
     def _prepare_system(self, A, b, refine_tol: float | None = None,
@@ -396,12 +397,13 @@ class SolveEngine:
             for slotN, items in sorted(buckets.items()):
                 k = len(items)
                 slotB = self._slot(k)
-                A = np.empty((slotB, slotN, slotN), dtype)
-                rhs = np.zeros((slotB, slotN), dtype)
-                for j, (_, prep) in enumerate(items):
-                    A[j] = prep.A
-                    rhs[j] = prep.b
-                A[k:] = np.eye(slotN, dtype=dtype)  # identity pad systems
+                with obs.span(obs.SERVE_STAGE):
+                    A = np.empty((slotB, slotN, slotN), dtype)
+                    rhs = np.zeros((slotB, slotN), dtype)
+                    for j, (_, prep) in enumerate(items):
+                        A[j] = prep.A
+                        rhs[j] = prep.b
+                    A[k:] = np.eye(slotN, dtype=dtype)  # identity pad systems
                 bplan = self._batched_plan(slotB, slotN)
                 fact = bplan.execute(A)
                 # Pre-cast the RHS to the plain solve's arithmetic dtype on
@@ -416,7 +418,7 @@ class SolveEngine:
                     rhs_in = rhs.astype(sdt, copy=False)
                 else:
                     rhs_in = rhs
-                X = np.asarray(jax.block_until_ready(fact.solve(rhs_in)))
+                X = obs.to_host(fact.solve(rhs_in))
                 for j, (i, prep) in enumerate(items):
                     results[i] = X[j, :prep.n]
                 # Second pass: refinement on the lanes that asked for it.

@@ -1,0 +1,176 @@
+"""The solver's trace spans and device scopes (`repro.obs`).
+
+A CPU profile around one small plan/execute/solve and one AsyncSolveEngine
+flush must hold every `repro.*` span, nested as `repro.obs` documents and
+carrying its arguments; the compiled LU programs must carry every `lu.*`
+scope in their `op_name` metadata; and with no profiler running the span
+helpers are plain context managers.
+"""
+
+from __future__ import annotations
+
+import glob
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from repro import obs
+from repro.api import SolverConfig, plan
+from repro.serving import AsyncSolveEngine
+
+N = 256
+F32 = 4
+
+
+class FakeClock:
+    def __init__(self):
+        self.t = 0.0
+
+    def __call__(self):
+        return self.t
+
+
+def _system(n, rng):
+    A = rng.standard_normal((n, n)).astype(np.float32) + n * np.eye(n, dtype=np.float32)
+    return A, rng.standard_normal(n).astype(np.float32)
+
+
+@pytest.fixture(scope="module")
+def profile(tmp_path_factory):
+    """Spans of one profiled solution and one profiled flush:
+    name -> [(start_ns, end_ns, args, thread)]."""
+    rng = np.random.default_rng(3)
+    A, b = _system(N, rng)
+    A_dev = jnp.asarray(A)
+    p = plan(N, SolverConfig(strategy="sequential", v=32))
+    jax.block_until_ready(p.execute(A_dev).solve(b))  # compile outside the trace
+    clock = FakeClock()
+    eng = AsyncSolveEngine(64, strategy="sequential", v=8, start=False,
+                           clock=clock, max_batch=4)
+    eng.warm_slots(sizes=[64, 20], max_batch=4)
+    systems = [_system(n, rng) for n in (64, 20, 64)]
+    out = tmp_path_factory.mktemp("profile")
+    with jax.profiler.trace(str(out)):
+        x = np.asarray(jax.block_until_ready(p.execute(A_dev).solve(b)))
+        futs = []
+        for i, (Ai, bi) in enumerate(systems):
+            clock.t = 0.5 * i
+            futs.append(eng.submit(Ai, bi))
+        clock.t = 2.0
+        assert eng.pump(force=True) == 3
+    xs = [f.result() for f in futs]
+    eng.close()
+    assert np.abs(A @ x - b).max() < 1e-3 * np.abs(b).max() * N
+    for (Ai, bi), xi in zip(systems, xs):
+        assert np.abs(Ai @ xi - bi).max() < 1e-3 * N
+
+    from jax.profiler import ProfileData
+
+    path, = glob.glob(str(out / "**" / "*.xplane.pb"), recursive=True)
+    spans: dict[str, list] = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name != "/host:CPU":
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith("repro."):
+                    spans.setdefault(e.name, []).append(
+                        (e.start_ns, e.end_ns, dict(e.stats), line.name))
+    return spans
+
+
+def _inside(inner, outer):
+    return (outer[0] <= inner[0] and inner[1] <= outer[1]) and inner[3] == outer[3]
+
+
+def _within(spans, outer):
+    return [s for s in spans if _inside(s, outer)]
+
+
+def test_every_span_recorded(profile):
+    assert set(obs.SPANS) <= set(profile)
+    for name, found in profile.items():
+        assert name in obs.SPANS, name
+        if name.startswith(obs.COPY_PREFIX):
+            assert all(s[2]["bytes"] > 0 for s in found), name
+
+
+def test_solution_spans_nest_with_their_bytes(profile):
+    solution = [s for s in profile[obs.EXECUTE]
+                if not any(_inside(s, f) for f in profile[obs.SERVE_FLUSH])]
+    execute, = solution
+    factor, = _within(profile[obs.FACTOR], execute)
+    down = _within(profile[obs.COPY_D2H], execute)
+    up = _within(profile[obs.COPY_H2D], execute)
+    # A comes off the device, goes back up, and F and rows come down
+    assert [s[2]["bytes"] for s in up] == [N * N * F32]
+    assert sorted(s[2]["bytes"] for s in down) == [N * F32, N * N * F32, N * N * F32]
+    assert all(s[1] <= factor[0] for s in up)
+    assert max(s[0] for s in down) >= factor[1]
+    solve, = [s for s in profile[obs.SOLVE] if s[0] >= execute[1]
+              and not any(_inside(s, f) for f in profile[obs.SERVE_FLUSH])]
+    # F, b and rows go up again for the solve
+    assert sorted(s[2]["bytes"] for s in _within(profile[obs.COPY_H2D], solve)) == [
+        N * F32, N * F32, N * N * F32]
+
+
+def test_flush_span_args_and_children(profile):
+    flush, = profile[obs.SERVE_FLUSH]
+    args = flush[2]
+    assert set(obs.FLUSH_ARGS) <= set(args)
+    assert args["systems"] == 3
+    # submitted at 0.0, 0.5, 1.0 on the engine's clock, drained at 2.0
+    assert args["wait_s_sum"] == pytest.approx(4.5)
+    assert args["wait_s_max"] == pytest.approx(2.0)
+    stages = _within(profile[obs.SERVE_STAGE], flush)
+    executes = _within(profile[obs.EXECUTE], flush)
+    solves = _within(profile[obs.SOLVE], flush)
+    assert len(stages) == len(executes) == len(solves) == 2  # slots 32 and 64
+    for stage, execute in zip(sorted(stages), sorted(executes)):
+        assert stage[1] <= execute[0]
+    assert _within(profile[obs.FACTOR], flush)
+    assert len(_within(profile[obs.COPY_D2H], flush)) == 2 * 3  # F, rows, X per slot
+    assert len(_within(profile[obs.COPY_H2D], flush)) == 2 * 4  # A; F, b, rows
+    complete, = _within(profile[obs.SERVE_COMPLETE], flush)
+    assert complete[0] >= max(s[1] for s in solves)
+
+
+@pytest.mark.parametrize("batched", [False, True])
+def test_lu_program_carries_every_scope(batched):
+    from repro.core.lu.sequential import (
+        lu_masked_sequential,
+        lu_masked_sequential_batched,
+    )
+
+    fn = lu_masked_sequential_batched if batched else lu_masked_sequential
+    shape = (2, 64, 64) if batched else (64, 64)
+    hlo = fn.lower(jax.ShapeDtypeStruct(shape, jnp.float32), v=32,
+                   backend="pallas").compile().as_text()
+    names = set(re.findall(r'op_name="([^"]*)"', hlo))
+    for scope in obs.LU_SCOPES:
+        assert any(f"/{scope}/" in n for n in names), scope
+    # the layout ops sit inside the panel step
+    layout = [n for n in names if f"/{obs.LU_LAYOUT}/" in n]
+    assert all(n.index(obs.LU_PANEL) < n.index(obs.LU_LAYOUT) for n in layout)
+
+
+def test_span_helpers_are_plain_context_managers():
+    assert not jax.profiler.TraceAnnotation.is_enabled()
+    names = obs.SPANS + obs.LU_SCOPES
+    assert len(set(names)) == len(names)
+    assert all(n.startswith("repro.") for n in obs.SPANS)
+    assert all(n.startswith("lu.") for n in obs.LU_SCOPES)
+    with obs.span(obs.SERVE_FLUSH, flush=0, systems=1) as s:
+        s.set_metadata(wait_s_sum=0.0)
+    with obs.scope(obs.LU_PANEL):
+        assert float(jnp.ones(3).sum()) == 3.0
+    host = np.arange(6, dtype=np.float32)
+    dev = obs.to_device(host)
+    assert isinstance(dev, jax.Array) and obs.to_device(dev) is dev
+    assert obs.to_device(host, dtype=jnp.int32).dtype == jnp.int32
+    back = obs.to_host(dev)
+    assert isinstance(back, np.ndarray) and (back == host).all()
+    assert obs.to_host(back) is back
