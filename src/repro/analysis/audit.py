@@ -411,11 +411,12 @@ def lint_pallas_fn(fn, avals, name: str,
         gm = eqn.params["grid_mapping"]
         kjaxpr = eqn.params["jaxpr"]
         grid = tuple(gm.grid)
+        n_dyn = getattr(gm, "num_dynamic_grid_bounds", 0)  # lead the operands
         n_idx = getattr(gm, "num_index_operands", 0)
         n_in = gm.num_inputs
         n_out = gm.num_outputs
         bms = list(gm.block_mappings)
-        arrays = [v.aval for v in eqn.invars][n_idx:n_idx + n_in]
+        arrays = [v.aval for v in eqn.invars][n_dyn + n_idx:n_dyn + n_idx + n_in]
         arrays += [v.aval for v in eqn.outvars][:n_out]
 
         # -- grid/block divisibility --------------------------------------
@@ -442,11 +443,14 @@ def lint_pallas_fn(fn, avals, name: str,
         def _bytes(a):
             return int(np.prod(a.shape)) * np.dtype(a.dtype).itemsize
 
-        pipelined = 2 if math.prod(grid) > 1 else 1  # double buffering
+        # double buffering; a dynamic extent may exceed 1
+        static = all(isinstance(g, int) for g in grid)
+        pipelined = 2 if not static or math.prod(grid) > 1 else 1
         block_bytes = sum(_bytes(a) for a in block_refs)
         scratch_bytes = sum(_bytes(a) for a in scratch_refs)
         vmem = block_bytes * pipelined + scratch_bytes
-        data = {"grid": list(grid), "block_bytes": block_bytes,
+        data = {"grid": [g if isinstance(g, int) else None for g in grid],
+                "block_bytes": block_bytes,
                 "scratch_bytes": scratch_bytes, "vmem_bytes": vmem,
                 "budget": vmem_budget}
         if vmem > vmem_budget:

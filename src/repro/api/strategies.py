@@ -26,10 +26,11 @@ def _devices(out: jax.Array) -> tuple[str, ...]:
     return tuple(str(s.device) for s in out.addressable_shards)
 
 
-def _factor(fn, A):
-    """Upload A, then run the factor program `fn` to ready outputs."""
+def _factor(fn, A, **args):
+    """Upload A, then run the factor program `fn` to ready outputs; `args`
+    go on its `repro.execute.factor` span."""
     A = obs.to_device(A)
-    with obs.span(obs.FACTOR):
+    with obs.span(obs.FACTOR, **args):
         return jax.block_until_ready(fn(A))
 
 
@@ -86,15 +87,24 @@ def build_sequential(N: int, config: SolverConfig, mesh=None) -> FactorizationPl
         return lu_masked_sequential(A, v=v, backend=backend)
 
     fn = jax.jit(full_precision(_traced))
+    shape = (N, N) if config.B is None else (config.B, N, N)
+    aval = _compute_aval(shape, config)
+    # The single-system Pallas update visits only the trailing column
+    # window; the batched kernel and the ref backend update every column.
+    share = 1.0
+    if backend == "pallas" and not batched:
+        from repro.kernels.fused_schur import schur_tile_share
+        from repro.kernels.ops import schur_tiles
+
+        share = schur_tile_share(N, v, schur_tiles(N, N, v, aval.dtype)[1])
 
     def run(A):
-        F, rows = _factor(fn, A)
+        F, rows = _factor(fn, A, schur_tile_share=share)
         return obs.to_host(F), obs.to_host(rows).astype(np.int64), _devices(F)
 
     p._run = run
     p._fn = fn
-    shape = (N, N) if config.B is None else (config.B, N, N)
-    p._in_avals = (_compute_aval(shape, config),)
+    p._in_avals = (aval,)
     return p
 
 

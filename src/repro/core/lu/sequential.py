@@ -97,8 +97,10 @@ def lu_masked_sequential(A: jax.Array, v: int = 32, backend: str = "ref"):
             # Trailing update: A11 -= L10 @ U01.
             L10 = Fp * active[:, None]  # multipliers of still-active rows
             # Steps 5+6 fused: U01 = L00^-1 R01 and the trailing update in one
-            # backend call (R01 is pre-masked, so U01 comes out masked columnwise).
-            F, U01 = bk.fused_trsm_schur(F, L00, R01, L10 * active[:, None], unit=True)
+            # backend call (R01 is pre-masked, so U01 comes out masked columnwise
+            # and the columns left of c0 + v need not be visited).
+            F, U01 = bk.fused_trsm_schur(F, L00, R01, L10 * active[:, None],
+                                         unit=True, c_start=c0 + v)
         with obs.scope(obs.LU_PIVOT_WRITEBACK):
             # Write U01 into the pivot rows' trailing columns.
             F = F * (1.0 - piv_onehot.sum(0)[:, None] * colmask[None, :]) + piv_onehot.T @ (

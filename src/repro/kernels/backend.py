@@ -67,13 +67,16 @@ class KernelBackend(Protocol):
         ...
 
     def fused_trsm_schur(self, A: jax.Array, L00: jax.Array, R01: jax.Array,
-                         L10: jax.Array, *, unit: bool = True):
+                         L10: jax.Array, *, unit: bool = True, c_start=0):
         """Steps 5+6 fused: U01 = L00^-1 R01, then A - L10 @ U01.
 
         Returns (A_new, U01).  The fused form keeps U01 resident between the
         triangular solve and the trailing update (no HBM round-trip); the
         forward substitution is columnwise independent, so the result is
         bit-compatible with the trsm_left_lower -> schur_update composition.
+        `c_start` (an int32 scalar, traced or not) is the first column that
+        can change: R01 is zero left of it, so a backend may leave those
+        columns of A, and of U01 (zero), as they are.
         """
         ...
 
@@ -218,7 +221,8 @@ class RefBackend:
             return out.astype(A.dtype)
         return A - L @ U
 
-    def fused_trsm_schur(self, A, L00, R01, L10, *, unit=True):
+    def fused_trsm_schur(self, A, L00, R01, L10, *, unit=True, c_start=0):
+        del c_start  # the whole matrix: columns left of it come out unchanged
         U01 = self.trsm_left_lower(L00, R01, unit=unit)
         if _wants_f32_accum(A):
             out = A.astype(jnp.float32) - jnp.matmul(
@@ -255,7 +259,8 @@ class PallasBackend:
     """The MXU-tiled Pallas kernels (`repro.kernels.ops`); the ops wrappers
     auto-fit block sizes to the local shapes (largest divisor of each
     dimension not exceeding the 128x128 MXU tile, 256 for the long TRSM
-    dimension)."""
+    dimension; the single-system fused TRSM -> Schur grid takes the
+    largest tiles its VMEM holds, `ops.schur_tiles`)."""
 
     name = "pallas"
 
@@ -285,10 +290,10 @@ class PallasBackend:
 
         return ops.schur_update(A, L, U)
 
-    def fused_trsm_schur(self, A, L00, R01, L10, *, unit=True):
+    def fused_trsm_schur(self, A, L00, R01, L10, *, unit=True, c_start=0):
         from repro.kernels import ops
 
-        return ops.fused_trsm_schur(A, L00, R01, L10, unit=unit)
+        return ops.fused_trsm_schur(A, L00, R01, L10, unit=unit, c_start=c_start)
 
     # Batched = the batch-grid kernels: one launch covers all B systems.
 

@@ -11,7 +11,8 @@ not exceed it and that Mosaic can tile — a multiple of 128 on the lane
 or else the whole dimension, which Mosaic always accepts.  So a direct
 `ops.schur_update` / `ops.trsm_*` call on a matrix smaller (or merely not a
 multiple) of the 128/256 defaults works instead of tripping the kernels'
-exact-cover assertions.
+exact-cover assertions.  The single-system fused TRSM -> Schur kernel has
+no fixed default: its tiles come from the shape (`schur_tiles`).
 """
 
 from __future__ import annotations
@@ -19,6 +20,7 @@ from __future__ import annotations
 import functools
 
 import jax
+import jax.numpy as jnp
 
 from repro.kernels import chol_panel as _cp
 from repro.kernels import flash_attention as _fa
@@ -42,13 +44,16 @@ def _sublane(x) -> int:
     return 8 * (4 // x.dtype.itemsize)
 
 
+def _tiles(dim: int, align: int) -> list[int]:
+    """The tiles a grid may cut `dim` into: the multiples of `align` (Mosaic's
+    tiling) that divide it (exact cover), or the whole dim when none does."""
+    return [d for d in range(align, dim + 1, align) if dim % d == 0] or [dim]
+
+
 def _fit(block: int, dim: int, align: int) -> int:
-    """Largest tile <= min(block, dim) dividing dim (grids need exact cover)
-    and a multiple of `align`; the whole dim when no such tile exists."""
-    for d in range(min(block, dim) // align * align, 0, -align):
-        if dim % d == 0:
-            return d
-    return dim
+    """Largest of `_tiles(dim, align)` not above `block`; the whole dim
+    when none is."""
+    return max((d for d in _tiles(dim, align) if d <= block), default=dim)
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "bk", "interpret"))
@@ -93,20 +98,60 @@ def trsm_left_lower(L, B, bc=256, unit=True, interpret=None):
                             _interp(interpret))
 
 
+# Scoped VMEM a Mosaic kernel may use by default on a v5e TensorCore.
+SCOPED_VMEM = 16 * 2**20
+
+
+def _pad(n: int, align: int) -> int:
+    return -(-n // align) * align
+
+
+def schur_vmem_bytes(bm: int, bc: int, v: int, dtype) -> int:
+    """VMEM of the single-system fused TRSM -> Schur kernel at tiles
+    (bm, bc): its blocks double-buffered (A in and out, L10, R01, U01,
+    L00, padded to Mosaic's (sublane, 128) tiling), the f32 U scratch and
+    the f32 product of one row step."""
+    s = jnp.dtype(dtype).itemsize
+    vs, vl = _pad(v, 8 * (4 // s)), _pad(v, LANE)
+    blocks = (2 * bm * bc + bm * vl + 2 * vs * bc + vs * vl) * s
+    return 2 * blocks + _pad(v, 8) * bc * 4 + bm * bc * 4
+
+
+def schur_tiles(M: int, C: int, v: int, dtype) -> tuple[int, int]:
+    """(bm, bc) of the single-system fused TRSM -> Schur grid for A [M, C]:
+    of `_tiles` of each dimension, the largest whose VMEM fits SCOPED_VMEM
+    (the fewer bytes among equal areas), else the smallest.  A grid step
+    carries a fixed cost, so the tiles grow until VMEM stops them; a
+    matrix that fits whole is one tile."""
+    sub = 8 * (4 // jnp.dtype(dtype).itemsize)
+    pairs = [(bm, bc) for bm in _tiles(M, sub) for bc in _tiles(C, LANE)]
+    fit = [p for p in pairs if schur_vmem_bytes(*p, v, dtype) <= SCOPED_VMEM]
+    if not fit:
+        return min(pairs, key=lambda p: p[0] * p[1])
+    return max(fit, key=lambda p: (p[0] * p[1], -schur_vmem_bytes(*p, v, dtype)))
+
+
 @functools.partial(jax.jit, static_argnames=("bm", "bc", "unit", "interpret"))
-def _fused_trsm_schur(A, L00, R01, L10, bm, bc, unit, interpret):
-    return _fs.fused_trsm_schur(A, L00, R01, L10, bm=bm, bc=bc, unit=unit,
-                                interpret=interpret)
+def _fused_trsm_schur(A, L00, R01, L10, c_start, bm, bc, unit, interpret):
+    return _fs.fused_trsm_schur(A, L00, R01, L10, c_start, bm=bm, bc=bc,
+                                unit=unit, interpret=interpret)
 
 
-def fused_trsm_schur(A, L00, R01, L10, bm=128, bc=128, unit=True, interpret=None):
-    """U01 = L00^-1 R01 and A - L10 @ U01 in one VMEM-resident grid.
+def fused_trsm_schur(A, L00, R01, L10, bm=None, bc=None, unit=True,
+                     interpret=None, c_start=0):
+    """U01 = L00^-1 R01 and A - L10 @ U01 in one VMEM-resident grid, over
+    the column tiles from the one holding `c_start` on (a traced int32
+    scalar; R01 must be zero left of it).
 
-    Returns (A_new, U01) — see `repro.kernels.fused_schur`.
+    Tiles default to `schur_tiles` of the shape; a given `bm`/`bc` is fit
+    like every other kernel's.  Returns (A_new, U01) — see
+    `repro.kernels.fused_schur`.
     """
     M, C = A.shape
-    return _fused_trsm_schur(A, L00, R01, L10, _fit(bm, M, _sublane(A)),
-                             _fit(bc, C, LANE), unit, _interp(interpret))
+    tbm, tbc = schur_tiles(M, C, L00.shape[0], A.dtype)
+    return _fused_trsm_schur(A, L00, R01, L10, jnp.asarray(c_start, jnp.int32),
+                             _fit(bm or tbm, M, _sublane(A)),
+                             _fit(bc or tbc, C, LANE), unit, _interp(interpret))
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))

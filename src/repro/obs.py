@@ -21,6 +21,7 @@ import numpy as np
 # host spans
 EXECUTE = "repro.execute"  # all of FactorizationPlan.execute
 FACTOR = "repro.execute.factor"  # factor program: dispatch to a ready F
+FACTOR_ARGS = ("schur_tile_share",)  # sequential plans: Schur tiles computed
 SOLVE = "repro.solve"  # Factorization.solve: uploads and dispatch
 COPY_H2D = "repro.copy.h2d"  # host -> device upload; arg bytes
 COPY_D2H = "repro.copy.d2h"  # device -> host download; arg bytes

@@ -103,18 +103,42 @@ class TestTrsm:
         )
 
 
+# where the window starts, relative to the column tiles of width bc
+C_STARTS = {
+    "zero": lambda C, bc: 0,
+    "in_first_tile": lambda C, bc: 5,
+    "on_boundary": lambda C, bc: bc,
+    "in_last_tile": lambda C, bc: C - 3,
+    "end": lambda C, bc: C,
+}
+
+
 class TestFusedTrsmSchur:
-    @pytest.mark.parametrize("M,C,v", [(128, 128, 16), (256, 128, 32), (64, 96, 8)])
+    @pytest.mark.parametrize("M,C,v", [(128, 128, 16), (256, 128, 32), (64, 96, 8),
+                                       (64, 384, 16)])
     @pytest.mark.parametrize("unit", [True, False])
-    def test_sweep(self, M, C, v, unit):
+    @pytest.mark.parametrize("where", sorted(C_STARTS))
+    def test_sweep(self, M, C, v, unit, where):
+        bm, bc = 64, 128  # several column tiles where C allows (bc fits to 96)
+        c_start = C_STARTS[where](C, min(bc, C))
         # 0.3x off-diagonal keeps the forward substitution well-conditioned
         # (growth compounds through the Schur subtract at v=32 otherwise)
         L00 = 0.3 * jnp.tril(_rand((v, v)), -1) + (1.0 if unit else 2.0) * jnp.eye(v)
         A, R01, L10 = _rand((M, C)), _rand((v, C)), _rand((M, v))
-        gA, gU = ops.fused_trsm_schur(A, L00, R01, L10, bm=64, bc=64, unit=unit)
+        R01 = R01 * (jnp.arange(C) >= c_start)
+        # the tiles left of the first visited one are never read: fill them
+        # with values whose product with anything but 0 overflows
+        left = min(c_start // bc, (C - 1) // bc) * bc
+        A = A.at[:, :left].set(3e38)
+        gA, gU = ops.fused_trsm_schur(A, L00, R01, L10, bm=bm, bc=bc, unit=unit,
+                                      c_start=c_start)
         wA, wU = ref.fused_trsm_schur(A, L00, R01, L10, unit=unit)
         np.testing.assert_allclose(np.asarray(gU), np.asarray(wU), rtol=2e-4, atol=2e-4)
         np.testing.assert_allclose(np.asarray(gA), np.asarray(wA), rtol=2e-4, atol=2e-4)
+        gA, gU = np.asarray(gA), np.asarray(gU)
+        np.testing.assert_array_equal(gA[:, :c_start], np.asarray(A)[:, :c_start])
+        np.testing.assert_array_equal(gU[:, :c_start], 0.0)
+        assert np.isfinite(gA).all() and np.isfinite(gU).all()
 
     def test_fused_equals_two_call_composition(self):
         v, M, C = 16, 64, 128
@@ -126,6 +150,48 @@ class TestFusedTrsmSchur:
         np.testing.assert_array_equal(
             np.asarray(gA), np.asarray(ops.schur_update(A, L10, U))
         )
+
+
+class TestSchurTiles:
+    """The single-system fused kernel's tiles come from the shape."""
+
+    def test_chip_size_fits_vmem_in_few_steps(self):
+        M = C = 16384
+        for dtype in (jnp.float32, jnp.bfloat16):
+            bm, bc = ops.schur_tiles(M, C, 32, dtype)
+            assert ops.schur_vmem_bytes(bm, bc, 32, dtype) <= ops.SCOPED_VMEM
+            assert (M // bm) * (C // bc) <= 1024
+            assert bm % 8 == 0 and bc % 128 == 0 and M % bm == 0 and C % bc == 0
+
+    @pytest.mark.parametrize("M,C,v", [(24, 40, 8), (64, 96, 8), (256, 256, 32),
+                                       (4104, 384, 32), (2048, 2048, 32), (1000, 1000, 8)])
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    def test_small_and_odd_shapes_keep_exact_cover(self, M, C, v, dtype):
+        bm, bc = ops.schur_tiles(M, C, v, dtype)
+        sub = 8 * (4 // jnp.dtype(dtype).itemsize)
+        assert (bm, bc) == (ops._fit(bm, M, sub), ops._fit(bc, C, ops.LANE))
+        if ops.schur_vmem_bytes(M, C, v, dtype) <= ops.SCOPED_VMEM:
+            assert (bm, bc) == (M, C)  # a matrix that fits is one tile
+
+    def test_larger_tiles_do_not_fit(self):
+        bm, bc = ops.schur_tiles(16384, 16384, 32, jnp.float32)
+        assert ops.schur_vmem_bytes(2 * bm, bc, 32, jnp.float32) > ops.SCOPED_VMEM
+        assert ops.schur_vmem_bytes(bm, 2 * bc, 32, jnp.float32) > ops.SCOPED_VMEM
+
+    def test_tile_share(self):
+        from repro.kernels.fused_schur import schur_tile_share
+
+        assert schur_tile_share(16384, 32, 512) == pytest.approx(0.5137, abs=1e-4)
+        assert schur_tile_share(256, 32, 256) == 1.0  # one tile: always visited
+        shares = [schur_tile_share(4096, 32, bc) for bc in (128, 256, 512, 1024, 4096)]
+        assert shares == sorted(shares) and 0.5 < shares[0] < shares[-1] == 1.0
+        # counted tile by tile: tile j is visited while the window starts
+        # before its end, and the last tile always
+        N, v, bc = 2048, 128, 256
+        nt = N // bc
+        visited = sum(1 for t in range(N // v) for j in range(nt)
+                      if (t + 1) * v < (j + 1) * bc or j == nt - 1)
+        assert schur_tile_share(N, v, bc) == visited / (N // v * nt)
 
 
 class TestFlashAttention:

@@ -67,6 +67,12 @@ def profile(tmp_path_factory):
     for (Ai, bi), xi in zip(systems, xs):
         assert np.abs(Ai @ xi - bi).max() < 1e-3 * N
 
+    return _host_spans(out)
+
+
+def _host_spans(out):
+    """name -> [(start_ns, end_ns, args, thread)] of the `repro.*` spans
+    in the profile written under `out`."""
     from jax.profiler import ProfileData
 
     path, = glob.glob(str(out / "**" / "*.xplane.pb"), recursive=True)
@@ -136,6 +142,26 @@ def test_flush_span_args_and_children(profile):
     assert len(_within(profile[obs.COPY_H2D], flush)) == 2 * 4  # A; F, b, rows
     complete, = _within(profile[obs.SERVE_COMPLETE], flush)
     assert complete[0] >= max(s[1] for s in solves)
+
+
+def test_factor_span_carries_schur_tile_share(profile, tmp_path):
+    from repro.kernels import ops
+    from repro.kernels.fused_schur import schur_tile_share
+
+    # the ref plan and the serving tier's batched plans update every column
+    for s in profile[obs.FACTOR]:
+        assert set(obs.FACTOR_ARGS) <= set(s[2])
+        assert s[2]["schur_tile_share"] == 1.0
+    # a Pallas plan whose trailing window crosses its column tiles
+    n, v = 2048, 128
+    p = plan(n, SolverConfig(strategy="sequential", backend="pallas", v=v))
+    A = np.random.default_rng(4).uniform(-0.5, 0.5, (n, n)).astype(np.float32)
+    with jax.profiler.trace(str(tmp_path)):
+        p.execute(A)
+    factor, = _host_spans(tmp_path)[obs.FACTOR]
+    share = schur_tile_share(n, v, ops.schur_tiles(n, n, v, jnp.float32)[1])
+    assert share < 1.0
+    assert factor[2]["schur_tile_share"] == pytest.approx(share)
 
 
 @pytest.mark.parametrize("batched", [False, True])
