@@ -8,6 +8,7 @@ the available devices into a concrete `FactorizationPlan`.
 
 from __future__ import annotations
 
+from collections.abc import Mapping
 from dataclasses import dataclass, replace
 
 import numpy as np
@@ -55,7 +56,8 @@ class SolverConfig:
               "none" is Cholesky-only — pivoting is meaningless for SPD
               matrices, so the Cholesky strategies normalize any requested
               pivot to "none" at resolve time and the LU strategies reject it.
-    grid:     explicit GridConfig; None lets the strategy choose one.
+    grid:     explicit GridConfig, or a mapping of its fields (as a JSON
+              file gives it); None lets the strategy choose one.
     dtype:    *working* dtype (normalized to its numpy name, so configs hash):
               the precision of the input matrix, the retained `A_ref`, the
               refinement residual, and the refined solution.
@@ -112,6 +114,8 @@ class SolverConfig:
     calibration: str | None = None
 
     def __post_init__(self):
+        if isinstance(self.grid, Mapping):
+            object.__setattr__(self, "grid", GridConfig(**self.grid))
         dt = np.dtype(self.dtype)
         if dt.kind == "c":
             raise ValueError(

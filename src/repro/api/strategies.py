@@ -135,7 +135,13 @@ def _resolve_conflux(N: int, config: SolverConfig) -> SolverConfig:
     if config.grid is not None:
         return config
     P_target = config.P_target or len(jax.devices())
-    grid = optimize_grid(N, P_target, config.M, v=config.v)
+    try:
+        grid = optimize_grid(N, P_target, config.M, v=config.v)
+    except ValueError as e:
+        raise ValueError(
+            f"{e}; or pin the grid yourself: SolverConfig(grid=GridConfig(Px, Py, "
+            f"c, v, N={N}))"
+        ) from e
     return config.with_(grid=grid)
 
 
@@ -150,17 +156,38 @@ def _shard_map(f, mesh, in_specs, out_specs):
                          check_vma=False)
 
 
+# How every block-cyclic plan lays its [Px, Py, R, C] blocks on the mesh:
+# one block per (px, py), the same block on every pz layer.
+_BLOCKS = ("px", "py", None, None)
+
+
+def _factor_blocks(fn, A, grid, mesh):
+    """Lay A out block-cyclically on the host, upload the blocks to their
+    devices, and run the factor program `fn` to ready outputs."""
+    from jax.sharding import NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    from repro.core.lu.conflux import block_cyclic_scatter
+
+    with obs.span(obs.LAYOUT, bytes=A.nbytes):
+        blocks = block_cyclic_scatter(A, grid.Px, grid.Py, grid.v)
+    return _factor(fn, obs.to_devices(blocks, NamedSharding(mesh, P(*_BLOCKS))))
+
+
+def _gather_blocks(Fblocks, grid):
+    """Download the factor blocks and undo the block-cyclic layout."""
+    from repro.core.lu.conflux import block_cyclic_gather
+
+    host = obs.to_host(Fblocks)
+    with obs.span(obs.LAYOUT, bytes=host.nbytes):
+        return block_cyclic_gather(host, grid.N, grid.v)
+
+
 def _build_shardmap_plan(N: int, config: SolverConfig, mesh=None) -> FactorizationPlan:
     """Shared builder for every block-cyclic shard_map strategy."""
     from jax.sharding import PartitionSpec as P
 
-    from repro.core.lu.conflux import (
-        _local_lu,
-        block_cyclic_gather,
-        block_cyclic_scatter,
-        lu_comm_volume,
-        make_lu_mesh,
-    )
+    from repro.core.lu.conflux import _local_lu, lu_comm_volume, make_lu_mesh
 
     grid = config.grid
     if grid is None:
@@ -181,16 +208,15 @@ def _build_shardmap_plan(N: int, config: SolverConfig, mesh=None) -> Factorizati
         _shard_map(
             full_precision(_traced),
             mesh=mesh,
-            in_specs=P("px", "py", None, None),
-            out_specs=(P("px", "py", None, None), P()),
+            in_specs=P(*_BLOCKS),
+            out_specs=(P(*_BLOCKS), P()),
         )
     )
 
     def run(A):
-        blocks = block_cyclic_scatter(A, grid.Px, grid.Py, grid.v)
-        Fblocks, rows = fn(blocks)
-        F = block_cyclic_gather(np.asarray(Fblocks), N, grid.v)
-        return F, np.asarray(rows).astype(np.int64), _devices(Fblocks)
+        Fblocks, rows = _factor_blocks(fn, A, grid, mesh)
+        F = _gather_blocks(Fblocks, grid)
+        return F, obs.to_host(rows).astype(np.int64), _devices(Fblocks)
 
     p._run = run
     p._fn = fn
@@ -309,11 +335,7 @@ def build_cholesky25d(N: int, config: SolverConfig, mesh=None) -> FactorizationP
     from jax.sharding import PartitionSpec as P
 
     from repro.core.cholesky.conflux25d import _local_chol, chol_comm_volume
-    from repro.core.lu.conflux import (
-        block_cyclic_gather,
-        block_cyclic_scatter,
-        make_lu_mesh,
-    )
+    from repro.core.lu.conflux import make_lu_mesh
 
     grid = config.grid
     if grid is None:
@@ -333,15 +355,14 @@ def build_cholesky25d(N: int, config: SolverConfig, mesh=None) -> FactorizationP
         _shard_map(
             full_precision(_traced),
             mesh=mesh,
-            in_specs=P("px", "py", None, None),
-            out_specs=P("px", "py", None, None),
+            in_specs=P(*_BLOCKS),
+            out_specs=P(*_BLOCKS),
         )
     )
 
     def run(A):
-        blocks = block_cyclic_scatter(A, grid.Px, grid.Py, grid.v)
-        Fblocks = fn(blocks)
-        L = block_cyclic_gather(np.asarray(Fblocks), N, grid.v)
+        Fblocks = _factor_blocks(fn, A, grid, mesh)
+        L = _gather_blocks(Fblocks, grid)
         return L, np.arange(N, dtype=np.int64), _devices(Fblocks)
 
     p._run = run

@@ -28,8 +28,17 @@ rendezvous), so this port executes them unconditionally with masked
 payloads — numerically identical, but the *executed* volume exceeds the
 schedule's.  Communication volume is therefore accounted by
 `lu_comm_volume`, which instruments the exact schedule (payload x group per
-collective call site) the way the paper instruments MPI with Score-P.  On a
-real TPU deployment the conditional schedule compiles and runs as-is.
+collective call site) the way the paper instruments MPI with Score-P.  The
+TPU program is the same one: it too runs the unconditional, masked
+collectives.  Running only the collectives the schedule involves is
+ROADMAP 1.6.
+
+Device scopes (`repro.obs`).  Each step of the windowed body runs in a
+`jax.named_scope`: the steps that do the sequential schedule's jobs take its
+names (`lu.panel`, `lu.pivot_gather`, `lu.update`, `lu.pivot_writeback`),
+and the three it lacks have their own (`lu.panel_reduce`, `lu.tournament`,
+`lu.bcast`).  The flat body, a test oracle, is scoped only where it shares
+`pivot_panel`.
 """
 
 from __future__ import annotations
@@ -40,6 +49,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core.lu.cost_models import conflux_model
 from repro.core.lu.grid import GridConfig
 from repro.core.windows import window_bucket_index, window_buckets
@@ -140,22 +150,24 @@ def _local_lu(cfg: GridConfig, pivot: str, backend: str, Aloc, *,
     def tournament(panel_vals, weights):
         """Local masked LUP -> butterfly merge along px.  Returns packed A00
         factors [v, v] (in elimination order) and winners' global ids [v]."""
-        _, order, ok = bk.panel_lup(panel_vals, weights, v)
-        cand_vals = panel_vals[order, :]  # original values of local winners
-        valid = ok & (weights[order] > 0)
-        cand_gids = jnp.where(valid, row_gid[order], -1)
-        for r in range(rounds):
-            perm = [(i, i ^ (1 << r)) for i in range(Px)]
-            other_vals = jax.lax.ppermute(cand_vals, "px", perm)
-            other_gids = jax.lax.ppermute(cand_gids, "px", perm)
-            vals2 = jnp.concatenate([cand_vals, other_vals], axis=0)  # [2v, v]
-            gids2 = jnp.concatenate([cand_gids, other_gids], axis=0)
-            w2 = (gids2 >= 0).astype(dtype)
-            _, order2, ok2 = bk.panel_lup(vals2, w2, v)
-            cand_vals = vals2[order2, :]
-            cand_gids = jnp.where(ok2, gids2[order2], -1)
-        A00p, order_f, ok_f = bk.panel_lup(cand_vals, (cand_gids >= 0).astype(dtype), v)
-        return A00p[order_f, :], jnp.where(ok_f, cand_gids[order_f], -1)
+        with obs.scope(obs.LU_PANEL):
+            _, order, ok = bk.panel_lup(panel_vals, weights, v)
+            cand_vals = panel_vals[order, :]  # original values of local winners
+            valid = ok & (weights[order] > 0)
+            cand_gids = jnp.where(valid, row_gid[order], -1)
+        with obs.scope(obs.LU_TOURNAMENT):
+            for r in range(rounds):
+                perm = [(i, i ^ (1 << r)) for i in range(Px)]
+                other_vals = jax.lax.ppermute(cand_vals, "px", perm)
+                other_gids = jax.lax.ppermute(cand_gids, "px", perm)
+                vals2 = jnp.concatenate([cand_vals, other_vals], axis=0)  # [2v, v]
+                gids2 = jnp.concatenate([cand_gids, other_gids], axis=0)
+                w2 = (gids2 >= 0).astype(dtype)
+                _, order2, ok2 = bk.panel_lup(vals2, w2, v)
+                cand_vals = vals2[order2, :]
+                cand_gids = jnp.where(ok2, gids2[order2], -1)
+            A00p, order_f, ok_f = bk.panel_lup(cand_vals, (cand_gids >= 0).astype(dtype), v)
+            return A00p[order_f, :], jnp.where(ok_f, cand_gids[order_f], -1)
 
     def partial_pivot(panel_vals, weights):
         """ScaLAPACK-style panel factorization: per column, a global argmax
@@ -184,7 +196,8 @@ def _local_lu(cfg: GridConfig, pivot: str, backend: str, Aloc, *,
             return (F, w, A00.at[k].set(prow), gids.at[k].set(g))
 
         init = (panel_vals, weights, jnp.zeros((v, v), dtype), jnp.full((v,), -1, jnp.int32))
-        _, _, A00, gids = jax.lax.fori_loop(0, v, col_round, init)
+        with obs.scope(obs.LU_PANEL):
+            _, _, A00, gids = jax.lax.fori_loop(0, v, col_round, init)
         return A00, gids
 
     def pivot_panel(t, panel, active):
@@ -197,8 +210,9 @@ def _local_lu(cfg: GridConfig, pivot: str, backend: str, Aloc, *,
             A00, piv_gids = tournament(panel, active)
         else:
             A00, piv_gids = partial_pivot(panel, active)
-        A00 = jax.lax.psum(A00 * ow, "py")
-        piv_gids = jax.lax.psum(jnp.where(is_owner_col, piv_gids, 0), "py")
+        with obs.scope(obs.LU_BCAST):
+            A00 = jax.lax.psum(A00 * ow, "py")
+            piv_gids = jax.lax.psum(jnp.where(is_owner_col, piv_gids, 0), "py")
         return A00, piv_gids, ow
 
     def step_flat(t, carry):
@@ -274,59 +288,72 @@ def _local_lu(cfg: GridConfig, pivot: str, backend: str, Aloc, *,
 
         def body(args):
             t, Aloc, Floc, active, rows = args
-            Awin = Aloc[:, c_start:]
+            with obs.scope(obs.LU_UPDATE):
+                Awin = Aloc[:, c_start:]
             cg = col_gid[c_start:]
             lc0 = (t // Py) * v
             lc0w = jnp.clip(lc0 - c_start, 0, wc - v)  # owner never clips
 
             # -- 1. Reduce the panel block-column over pz (window slice). -----
-            my_panel = jax.lax.dynamic_slice(Awin, (0, lc0w), (R, v))
-            panel = jax.lax.psum(my_panel, "pz")
+            with obs.scope(obs.LU_PANEL_REDUCE):
+                my_panel = jax.lax.dynamic_slice(Awin, (0, lc0w), (R, v))
+                panel = jax.lax.psum(my_panel, "pz")
 
             # -- 2+3. Pivoting + broadcast (identical to the flat body). ------
             A00, piv_gids, ow = pivot_panel(t, panel, active)
 
-            L00 = jnp.tril(A00, -1) + jnp.eye(v, dtype=dtype)
-            U00 = jnp.triu(A00)
-            lr, own = pivot_local_rows(piv_gids)
-            is_new_piv = jnp.zeros((R,), dtype).at[lr].add(own)
-            new_active = active * (1.0 - is_new_piv)
+            with obs.scope(obs.LU_PIVOT_GATHER):
+                L00 = jnp.tril(A00, -1) + jnp.eye(v, dtype=dtype)
+                U00 = jnp.triu(A00)
+                lr, own = pivot_local_rows(piv_gids)
+                is_new_piv = jnp.zeros((R,), dtype).at[lr].add(own)
+                new_active = active * (1.0 - is_new_piv)
 
             # -- 4. L10 on the owner column, broadcast along py. --------------
-            L10_own = bk.trsm_right_upper(panel * new_active[:, None], U00)
-            L10 = jax.lax.psum(L10_own * ow, "py")  # [R, v]
+            with obs.scope(obs.LU_PANEL):
+                L10_own = bk.trsm_right_upper(panel * new_active[:, None], U00)
+            with obs.scope(obs.LU_BCAST):
+                L10 = jax.lax.psum(L10_own * ow, "py")  # [R, v]
 
             # -- 5. Pivot rows gathered by index over (px, pz). ---------------
-            R01 = jax.lax.psum(
-                jnp.take(Awin, lr, axis=0) * own[:, None], ("px", "pz")
-            )  # [v, wc] current values
-            trailing = (cg >= (t + 1) * v).astype(dtype)
-            R01 = R01 * trailing[None, :]  # columnwise: same U01 as masking after
+            with obs.scope(obs.LU_PIVOT_GATHER):
+                R01 = jax.lax.psum(
+                    jnp.take(Awin, lr, axis=0) * own[:, None], ("px", "pz")
+                )  # [v, wc] current values
+                trailing = (cg >= (t + 1) * v).astype(dtype)
+                R01 = R01 * trailing[None, :]  # columnwise: same U01 as masking after
 
             # -- 6. Fused TRSM -> Schur on layer t % c: U01 never leaves the --
             #    kernel between the solve and the trailing update.
-            on_layer = (pz == (t % c)).astype(dtype)
-            Awin, U01 = bk.fused_trsm_schur(
-                Awin, L00, R01, L10 * (on_layer * new_active)[:, None], unit=True
-            )
+            with obs.scope(obs.LU_UPDATE):
+                on_layer = (pz == (t % c)).astype(dtype)
+                Awin, U01 = bk.fused_trsm_schur(
+                    Awin, L00, R01, L10 * (on_layer * new_active)[:, None], unit=True
+                )
 
             # -- 7. Factor write-back: one v-wide panel slab + an indexed -----
-            #    row scatter for the pivot rows' trailing columns — never a
-            #    full-block (or full-window) copy of Floc.
-            lc0c = jnp.clip(lc0, 0, C - v)
-            prev = jax.lax.dynamic_slice(Floc, (0, lc0c), (R, v))
-            was_piv = (1.0 - active)[:, None]
-            SA00 = jnp.zeros((R, v), dtype).at[lr].add(A00 * own[:, None])
-            Fpanel = L10 * new_active[:, None] + SA00 + prev * was_piv
-            cgs = jax.lax.dynamic_slice(col_gid, (lc0c,), (v,))
-            is_panel = (cgs >= t * v) & (cgs < (t + 1) * v)  # all-false off-owner
-            Floc = jax.lax.dynamic_update_slice(
-                Floc, jnp.where(is_panel[None, :], Fpanel, prev), (0, lc0c)
-            )
-            Floc = Floc.at[lr, c_start:].add(U01 * own[:, None])
+            #    row scatter for the pivot rows' trailing columns.  On a TPU
+            #    XLA expands that scatter-add into a loop over the v pivot
+            #    rows whose body copies the whole local Floc in and out, each
+            #    row (ROADMAP 1.6; `pivot_writeback_s` reads its time).
+            with obs.scope(obs.LU_PANEL):
+                lc0c = jnp.clip(lc0, 0, C - v)
+                prev = jax.lax.dynamic_slice(Floc, (0, lc0c), (R, v))
+                was_piv = (1.0 - active)[:, None]
+                SA00 = jnp.zeros((R, v), dtype).at[lr].add(A00 * own[:, None])
+                Fpanel = L10 * new_active[:, None] + SA00 + prev * was_piv
+                cgs = jax.lax.dynamic_slice(col_gid, (lc0c,), (v,))
+                is_panel = (cgs >= t * v) & (cgs < (t + 1) * v)  # all-false off-owner
+                Floc = jax.lax.dynamic_update_slice(
+                    Floc, jnp.where(is_panel[None, :], Fpanel, prev), (0, lc0c)
+                )
+            with obs.scope(obs.LU_PIVOT_WRITEBACK):
+                Floc = Floc.at[lr, c_start:].add(U01 * own[:, None])
 
-            Aloc = jax.lax.dynamic_update_slice(Aloc, Awin, (0, c_start))
-            rows = jax.lax.dynamic_update_slice(rows, piv_gids, (t * v,))
+            with obs.scope(obs.LU_UPDATE):
+                Aloc = jax.lax.dynamic_update_slice(Aloc, Awin, (0, c_start))
+            with obs.scope(obs.LU_PANEL):
+                rows = jax.lax.dynamic_update_slice(rows, piv_gids, (t * v,))
             return (Aloc, Floc, new_active, rows)
 
         return body

@@ -110,11 +110,12 @@ def schur_vmem_bytes(bm: int, bc: int, v: int, dtype) -> int:
     """VMEM of the single-system fused TRSM -> Schur kernel at tiles
     (bm, bc): its blocks double-buffered (A in and out, L10, R01, U01,
     L00, padded to Mosaic's (sublane, 128) tiling), the f32 U scratch and
-    the f32 product of one row step."""
+    the f32 product of one row step.  A column tile narrower than 128
+    lanes (a whole narrow window) takes 128 in VMEM."""
     s = jnp.dtype(dtype).itemsize
-    vs, vl = _pad(v, 8 * (4 // s)), _pad(v, LANE)
-    blocks = (2 * bm * bc + bm * vl + 2 * vs * bc + vs * vl) * s
-    return 2 * blocks + _pad(v, 8) * bc * 4 + bm * bc * 4
+    vs, vl, cl = _pad(v, 8 * (4 // s)), _pad(v, LANE), _pad(bc, LANE)
+    blocks = (2 * bm * cl + bm * vl + 2 * vs * cl + vs * vl) * s
+    return 2 * blocks + _pad(v, 8) * cl * 4 + bm * cl * 4
 
 
 def schur_tiles(M: int, C: int, v: int, dtype) -> tuple[int, int]:

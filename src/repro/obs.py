@@ -26,11 +26,12 @@ SOLVE = "repro.solve"  # Factorization.solve: uploads and dispatch
 COPY_H2D = "repro.copy.h2d"  # host -> device upload; arg bytes
 COPY_D2H = "repro.copy.d2h"  # device -> host download; arg bytes
 COPY_PREFIX = "repro.copy."
+LAYOUT = "repro.layout"  # host block-cyclic scatter or gather; arg bytes
 SERVE_FLUSH = "repro.serve.flush"  # one AsyncSolveEngine flush; args below
 SERVE_STAGE = "repro.serve.stage"  # stacking and padding of one size bucket
 SERVE_COMPLETE = "repro.serve.complete"  # set_result and latency records
 FLUSH_ARGS = ("flush", "systems", "wait_s_sum", "wait_s_max")
-SPANS = (EXECUTE, FACTOR, SOLVE, COPY_H2D, COPY_D2H,
+SPANS = (EXECUTE, FACTOR, SOLVE, COPY_H2D, COPY_D2H, LAYOUT,
          SERVE_FLUSH, SERVE_STAGE, SERVE_COMPLETE)
 
 # device scopes of the LU schedule (core/lu/sequential.py, kernels/lu_panel.py)
@@ -40,6 +41,12 @@ LU_PIVOT_GATHER = "lu.pivot_gather"  # one-hot, U00, L00, pivot rows R01
 LU_UPDATE = "lu.update"  # fused TRSM -> Schur and its glue
 LU_PIVOT_WRITEBACK = "lu.pivot_writeback"  # U01 into the pivot rows
 LU_SCOPES = (LU_PANEL, LU_LAYOUT, LU_PIVOT_GATHER, LU_UPDATE, LU_PIVOT_WRITEBACK)
+# steps of the distributed schedule (core/lu/conflux.py) that the sequential
+# one lacks; its other steps take the LU_SCOPES name of the same job
+LU_PANEL_REDUCE = "lu.panel_reduce"  # panel block-column summed over pz
+LU_TOURNAMENT = "lu.tournament"  # butterfly rounds over px and the last LUP
+LU_BCAST = "lu.bcast"  # A00, pivot ids and L10 broadcast over py
+CONFLUX_SCOPES = (LU_PANEL_REDUCE, LU_TOURNAMENT, LU_BCAST)
 
 
 # The copy helpers check this first: the serving executor is near
@@ -69,6 +76,16 @@ def to_device(x, dtype=None) -> jax.Array:
         out = jnp.asarray(x, dtype=dtype)
         s.set_metadata(bytes=out.nbytes)
     return out
+
+
+def to_devices(x, sharding) -> jax.Array:
+    """`jax.device_put(x, sharding)`: a host array split over the devices
+    of `sharding`; while a profiler runs, in one `repro.copy.h2d` span
+    carrying the host array's bytes, which ends as `to_device`'s does."""
+    if not _recording():
+        return jax.device_put(x, sharding)
+    with span(COPY_H2D, bytes=x.nbytes):
+        return jax.device_put(x, sharding)
 
 
 def to_host(x) -> np.ndarray:

@@ -173,6 +173,16 @@ class TestSchurTiles:
         if ops.schur_vmem_bytes(M, C, v, dtype) <= ops.SCOPED_VMEM:
             assert (bm, bc) == (M, C)  # a matrix that fits is one tile
 
+    @pytest.mark.parametrize("C", [32, 64, 96])
+    def test_narrow_window_takes_a_whole_lane_tile(self, C):
+        # the last windows of a conflux [2x2x1] plan at N=16384 are [8192, C]
+        # with C < 128, which Mosaic pads to 128 lanes in VMEM
+        assert ops.schur_vmem_bytes(4096, C, 32, jnp.float32) == \
+            ops.schur_vmem_bytes(4096, 128, 32, jnp.float32)
+        bm, bc = ops.schur_tiles(8192, C, 32, jnp.float32)
+        assert bc == C and bm < 8192
+        assert ops.schur_vmem_bytes(bm, bc, 32, jnp.float32) <= ops.SCOPED_VMEM
+
     def test_larger_tiles_do_not_fit(self):
         bm, bc = ops.schur_tiles(16384, 16384, 32, jnp.float32)
         assert ops.schur_vmem_bytes(2 * bm, bc, 32, jnp.float32) > ops.SCOPED_VMEM

@@ -10,7 +10,11 @@ helpers are plain context managers.
 from __future__ import annotations
 
 import glob
+import json
+import os
 import re
+import subprocess
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -97,7 +101,9 @@ def _within(spans, outer):
 
 
 def test_every_span_recorded(profile):
-    assert set(obs.SPANS) <= set(profile)
+    # the block-cyclic layout is the distributed plans' own: see
+    # test_conflux_layout_spans_each_way
+    assert set(obs.SPANS) - {obs.LAYOUT} <= set(profile)
     for name, found in profile.items():
         assert name in obs.SPANS, name
         if name.startswith(obs.COPY_PREFIX):
@@ -183,12 +189,52 @@ def test_lu_program_carries_every_scope(batched):
     assert all(n.index(obs.LU_PANEL) < n.index(obs.LU_LAYOUT) for n in layout)
 
 
+@pytest.fixture(scope="module")
+def conflux4():
+    """Scopes and spans of a conflux [2x2x1] plan on four host devices."""
+    here = os.path.dirname(os.path.abspath(__file__))
+    proc = subprocess.run(
+        [sys.executable, os.path.join(here, "multidev", "run_conflux_obs.py"), str(N)],
+        capture_output=True, text=True, timeout=600,
+        env={**os.environ, "PYTHONPATH": os.path.join(here, "..", "src")})
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    got = json.loads(proc.stdout.strip().splitlines()[-1])
+    execute, = [s for s in got["spans"] if s[0] == obs.EXECUTE]
+    got["in_execute"] = [s for s in got["spans"] if execute[1] <= s[1] and s[2] <= execute[2]]
+    return got
+
+
+def test_conflux_program_carries_every_scope(conflux4):
+    for scope in obs.LU_SCOPES + obs.CONFLUX_SCOPES:
+        assert any(f"/{scope}/" in n for n in conflux4["op_names"]), scope
+
+
+def test_conflux_layout_spans_each_way(conflux4):
+    inside = conflux4["in_execute"]
+    layout = [s for s in inside if s[0] == obs.LAYOUT]
+    assert [s[3]["bytes"] for s in layout] == [N * N * F32] * 2
+    factor, = [s for s in inside if s[0] == obs.FACTOR]
+    # A is scattered before the factor program runs, F gathered after it
+    assert layout[0][2] <= factor[1] and layout[1][1] >= factor[2]
+
+
+def test_conflux_blocks_go_up_in_one_sharded_copy(conflux4):
+    inside = conflux4["in_execute"]
+    factor, = [s for s in inside if s[0] == obs.FACTOR]
+    up = [s for s in inside if s[0] == obs.COPY_H2D]
+    assert [s[3]["bytes"] for s in up] == [N * N * F32]
+    assert up[0][2] <= factor[1]
+    # the factor blocks and the pivot order come down
+    down = [s[3]["bytes"] for s in inside if s[0] == obs.COPY_D2H]
+    assert sorted(down) == [N * F32, N * N * F32]
+
+
 def test_span_helpers_are_plain_context_managers():
     assert not jax.profiler.TraceAnnotation.is_enabled()
-    names = obs.SPANS + obs.LU_SCOPES
+    names = obs.SPANS + obs.LU_SCOPES + obs.CONFLUX_SCOPES
     assert len(set(names)) == len(names)
     assert all(n.startswith("repro.") for n in obs.SPANS)
-    assert all(n.startswith("lu.") for n in obs.LU_SCOPES)
+    assert all(n.startswith("lu.") for n in obs.LU_SCOPES + obs.CONFLUX_SCOPES)
     with obs.span(obs.SERVE_FLUSH, flush=0, systems=1) as s:
         s.set_metadata(wait_s_sum=0.0)
     with obs.scope(obs.LU_PANEL):
@@ -200,3 +246,6 @@ def test_span_helpers_are_plain_context_managers():
     back = obs.to_host(dev)
     assert isinstance(back, np.ndarray) and (back == host).all()
     assert obs.to_host(back) is back
+    one = jax.sharding.SingleDeviceSharding(jax.devices()[0])
+    put = obs.to_devices(host, one)
+    assert put.sharding == one and (np.asarray(put) == host).all()

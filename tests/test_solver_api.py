@@ -215,6 +215,20 @@ class TestValidation:
             plan(64, SolverConfig(strategy="conflux",
                                   grid=GridConfig(Px=1, Py=1, c=1, v=8, N=32)))
 
+    def test_no_feasible_grid_names_the_way_out(self):
+        # the local share of N=512 on 4 ranks outgrows the default M=2**14
+        with pytest.raises(ValueError, match=r"raise M or P\); or pin the grid yourself: "
+                                             r"SolverConfig\(grid=GridConfig\("):
+            resolve(512, SolverConfig(strategy="conflux", P_target=4))
+
+    def test_grid_given_as_a_mapping(self):
+        fields = {"Px": 2, "Py": 2, "c": 1, "v": 32, "N": 256}
+        cfg = SolverConfig(strategy="conflux", grid=fields)
+        assert cfg == SolverConfig(strategy="conflux", grid=GridConfig(**fields))
+        assert cfg.cache_key(256) == cfg.with_(v=None).cache_key(256)
+        with pytest.raises(TypeError):
+            SolverConfig(grid={"Px": 2, "Py": 2})
+
     def test_unknown_strategy_lists_available(self):
         with pytest.raises(KeyError, match="conflux"):
             plan(32, SolverConfig(strategy="does-not-exist"))

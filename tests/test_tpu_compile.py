@@ -8,7 +8,8 @@ described v5e:2x2 topology, at the widths `chip_smoke.py` runs (panels of
 R=16384 rows, v=32; batched slots of 32 systems of n=256), and check that
 the compiled program holds the Mosaic kernel (`tpu_custom_call`).  Two
 more compile the whole in-core LU and Cholesky plans at N=16384 and check
-that they fit the chip's 16 GiB.
+that they fit the chip's 16 GiB; the last compiles the N=16384 conflux plan
+on a [2x2x1] grid of all four chips, in f32 and with bf16 factors.
 
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and the test workers all import this
@@ -19,7 +20,9 @@ entry written without a chip cannot be read back).
 from __future__ import annotations
 
 import os
+import re
 
+import numpy as np
 import pytest
 
 import jax
@@ -34,10 +37,9 @@ HBM_BYTES = 16 * 2**30  # one v5e chip
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
-    from jax.sharding import SingleDeviceSharding
 
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     try:
@@ -47,9 +49,16 @@ def one_chip():
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", was)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(topo.devices[0])
 
 
 # kernel -> (ops function, operand shapes); every call runs interpret=False
@@ -101,6 +110,37 @@ def test_in_core_plan_compiles_for_v5e(one_chip, strategy, monkeypatch):
         jax.clear_caches()  # drop the traces made with Mosaic kernels
     assert "tpu_custom_call" in compiled.as_text()
     mem = compiled.memory_analysis()
+    used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes)
+    assert used < HBM_BYTES, f"{used / 2**30:.2f} GiB exceeds one chip"
+
+
+@pytest.mark.parametrize("compute_dtype", [None, "bfloat16"])
+def test_conflux_plan_compiles_for_v5e_2x2(topo, compute_dtype, monkeypatch):
+    """The N=16384 conflux pallas plan on a [2x2x1] grid of the host's four
+    chips: Mosaic kernels and collectives inside, each chip within HBM."""
+    from jax.sharding import Mesh, NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    from repro.api import GridConfig, SolverConfig, clear_plan_cache, plan
+
+    monkeypatch.setattr(ops, "_interp", lambda flag: False)
+    mesh = Mesh(np.asarray(topo.devices).reshape(2, 2, 1), ("px", "py", "pz"))
+    config = SolverConfig(strategy="conflux", backend="pallas",
+                          grid=GridConfig(2, 2, 1, v=V, N=N), compute_dtype=compute_dtype)
+    p = plan(N, config, mesh=mesh)
+    try:
+        aval = jax.ShapeDtypeStruct((2, 2, N // 2, N // 2), compute_dtype or jnp.float32,
+                                    sharding=NamedSharding(mesh, P("px", "py", None, None)))
+        compiled = p._fn.lower(aval).compile()
+    finally:
+        clear_plan_cache()
+        jax.clear_caches()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert re.search(r" all-reduce\(", text)
+    assert re.search(r" collective-permute(-start)?\(", text)
+    mem = compiled.memory_analysis()  # per chip: the program is one SPMD partition
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes)
     assert used < HBM_BYTES, f"{used / 2**30:.2f} GiB exceeds one chip"
