@@ -332,10 +332,13 @@ def _local_lu(cfg: GridConfig, pivot: str, backend: str, Aloc, *,
                 )
 
             # -- 7. Factor write-back: one v-wide panel slab + an indexed -----
-            #    row scatter for the pivot rows' trailing columns.  On a TPU
-            #    XLA expands that scatter-add into a loop over the v pivot
-            #    rows whose body copies the whole local Floc in and out, each
-            #    row (ROADMAP 1.6; `pivot_writeback_s` reads its time).
+            #    row scatter for the pivot rows' trailing columns.  The
+            #    scatter covers whole local rows, U01 padded with zeros on
+            #    the left: XLA:TPU cannot emit a scatter into a column slice
+            #    that does not start at 0, and expands one into a loop over
+            #    the v rows that copies the whole local Floc every row.  The
+            #    padding is exact: a pivot row's columns left of the window
+            #    gain +0, rows with own = 0 add zero rows.
             with obs.scope(obs.LU_PANEL):
                 lc0c = jnp.clip(lc0, 0, C - v)
                 prev = jax.lax.dynamic_slice(Floc, (0, lc0c), (R, v))
@@ -348,7 +351,9 @@ def _local_lu(cfg: GridConfig, pivot: str, backend: str, Aloc, *,
                     Floc, jnp.where(is_panel[None, :], Fpanel, prev), (0, lc0c)
                 )
             with obs.scope(obs.LU_PIVOT_WRITEBACK):
-                Floc = Floc.at[lr, c_start:].add(U01 * own[:, None])
+                Floc = Floc.at[lr].add(
+                    jnp.pad(U01 * own[:, None], ((0, 0), (c_start, 0)))
+                )
 
             with obs.scope(obs.LU_UPDATE):
                 Aloc = jax.lax.dynamic_update_slice(Aloc, Awin, (0, c_start))

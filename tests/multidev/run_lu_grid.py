@@ -14,10 +14,12 @@ import numpy as np
 import jax.numpy as jnp
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "..", "src"))
+sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
 from repro.api import SolverConfig, factor
 from repro.core.lu.grid import GridConfig
 from repro.core.lu.sequential import reconstruct
+from test_hotloop import assert_factors_match
 
 
 def check(res, A, tag, tol=5e-5):
@@ -27,6 +29,18 @@ def check(res, A, tag, tol=5e-5):
     assert err < tol, f"{tag}: reconstruction err {err}"
     assert sorted(res.rows.tolist()) == list(range(N)), f"{tag}: bad permutation"
     print(f"PASS {tag} err={err:.2e} comm/proc={res.comm['total']:.0f}")
+
+
+def check_windowed_flat(A, g, pivot):
+    """Windowed == flat step body on a grid with Py > 1 or c > 1, where the
+    window buckets with c_start > 0 write pivot rows back across devices."""
+    facts = {hl: factor(A, SolverConfig(strategy="conflux", grid=g, pivot=pivot,
+                                        hotloop=hl))
+             for hl in ("windowed", "flat")}
+    w, f = facts["windowed"], facts["flat"]
+    np.testing.assert_array_equal(w.rows, f.rows)
+    assert_factors_match(w.F, f.F, g.N)
+    check(w, A, f"windowed==flat {pivot} {g}")
 
 
 def main():
@@ -45,6 +59,11 @@ def main():
     A = rng.standard_normal((128, 128)).astype(np.float32)
     check(factor(A, SolverConfig(strategy="baseline2d", P_target=8, v=16)),
           A, "scalapack2d [2x4]")
+    A = rng.standard_normal((256, 256)).astype(np.float32)
+    for g in (GridConfig(Px=2, Py=2, c=1, v=8, N=256),
+              GridConfig(Px=2, Py=1, c=2, v=8, N=256)):
+        for pivot in ("tournament", "partial"):
+            check_windowed_flat(A, g, pivot)
     # auto grid selection end-to-end
     A = rng.standard_normal((128, 128)).astype(np.float32)
     res = factor(A, SolverConfig(strategy="auto", M=2048.0))

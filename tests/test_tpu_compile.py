@@ -9,7 +9,8 @@ R=16384 rows, v=32; batched slots of 32 systems of n=256), and check that
 the compiled program holds the Mosaic kernel (`tpu_custom_call`).  Two
 more compile the whole in-core LU and Cholesky plans at N=16384 and check
 that they fit the chip's 16 GiB; the last compiles the N=16384 conflux plan
-on a [2x2x1] grid of all four chips, in f32 and with bf16 factors.
+on a [2x2x1] grid of all four chips, in f32 and with bf16 factors, and
+checks that its pivot write-back is no loop that copies the local block.
 
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and the test workers all import this
@@ -144,3 +145,51 @@ def test_conflux_plan_compiles_for_v5e_2x2(topo, compute_dtype, monkeypatch):
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes)
     assert used < HBM_BYTES, f"{used / 2**30:.2f} GiB exceeds one chip"
+    # The pivot write-back stays a native scatter: no loop of its own, and
+    # no copy of the whole local block in any loop inside the step loop.
+    loops = [ln for ln in text.splitlines()
+             if " while(" in ln and "lu.pivot_writeback" in ln]
+    assert not loops, f"{len(loops)} write-back loops"
+    block = f"{'bf16' if compute_dtype else 'f32'}[{N // 2},{N // 2}]"
+    comps = _computations(text)
+    copies = [ln for name in _nested_loop_computations(comps)
+              for ln in comps[name] if re.search(re.escape(block) + r"\S* copy\(", ln)]
+    assert not copies, f"{len(copies)} whole-block copies in nested loops"
+
+
+def _computations(text):
+    """HLO text -> {computation name: its instruction lines}."""
+    comps, lines = {}, None
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%([\w.\-]+) .*\{$", line)
+        if head:
+            lines = comps.setdefault(head.group(1), [])
+        elif lines is not None and line.startswith("  "):
+            lines.append(line)
+    return comps
+
+
+def _reachable(comps, roots):
+    """Computations that `roots` run, themselves included."""
+    seen, todo = set(), list(roots)
+    while todo:
+        name = todo.pop()
+        if name in seen or name not in comps:
+            continue
+        seen.add(name)
+        for ln in comps[name]:
+            todo += re.findall(r"(?:body|condition|to_apply|calls)=%([\w.\-]+)", ln)
+            for group in re.findall(
+                    r"(?:branch_computations|called_computations)=\{([^}]*)\}", ln):
+                todo += re.findall(r"%([\w.\-]+)", group)
+    return seen
+
+
+def _nested_loop_computations(comps):
+    """Computations run by a `while` that itself runs inside a `while`."""
+    def bodies(names):
+        return [b for name in names for ln in comps[name] if " while(" in ln
+                for b in re.findall(r"body=%([\w.\-]+)", ln)]
+
+    inside_loops = _reachable(comps, bodies(comps))
+    return _reachable(comps, bodies(inside_loops))
